@@ -8,7 +8,7 @@ Codecs operate on raw little-endian chunk bytes:
                     for gradient compression — not for exact restarts).
   * ``int8+zlib`` — both.
 
-The int8 codec's math mirrors ``repro.kernels.ref.qsnap_ref`` exactly — the
+The int8 codec's result equals ``repro.kernels.ref.qsnap_ref``'s exactly — the
 Pallas kernel (device-side compression before D2H copy) and this host codec
 are interchangeable, and tests assert bit-identical round-trips between them.
 """
@@ -58,7 +58,12 @@ def quantize_int8(x: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     blocks = buf.reshape(-1, BLOCK)
     scales = np.max(np.abs(blocks), axis=1) * INV127
     scales = np.where(scales == 0, 1.0, scales).astype(np.float32)
-    codes = np.clip(np.rint(blocks / scales[:, None]), -127, 127).astype(np.int8)
+    # Round the exact quotient, not the f32 one: the f64 quotient of two
+    # f32 values lands on a half-integer only when the exact one does, and
+    # the device encoders decide the same rounding exactly without a
+    # correctly rounded divide (``repro.kernels.qsnap.int8_codes``).
+    q = blocks.astype(np.float64) / scales[:, None]
+    codes = np.clip(np.rint(q), -127, 127).astype(np.int8)
     return codes.reshape(-1), scales
 
 

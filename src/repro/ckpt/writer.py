@@ -603,6 +603,9 @@ class AsyncCheckpointer:
                 self.failed_saves += 1
                 if self._inflight is fut:
                     self._inflight = None
+            # process-wide mirror: outlives the writer (terminate drops it)
+            registry().inc("ckpt.failed_saves",
+                           note=f"{type(e).__name__}: {e}")
             if raise_error:
                 raise
 

@@ -69,6 +69,9 @@ class AppManager:
         self._ckpt_daemon_stop = threading.Event()
         self._ckpt_daemon: Optional[threading.Thread] = None
         self._next_ckpt: Dict[str, float] = {}
+        # coordinators whose swap-out image is being written (under
+        # coord.lock): periodic saves skip them
+        self._swapping_out: set = set()
         self._step_counter: Dict[str, int] = {}
         # At most one recovery/suspend action in flight per coordinator:
         # the monitor re-reports a fault every poll tick (~50 ms) for as
@@ -218,12 +221,19 @@ class AppManager:
     # ------------------------------------------------------------------
     # Checkpointing (paper §5.2: user-initiated / periodic / app-initiated)
     # ------------------------------------------------------------------
-    def checkpoint_now(self, coord_id: str, *, blocking: bool = True) -> int:
+    def checkpoint_now(self, coord_id: str, *, blocking: bool = True,
+                       periodic: bool = False) -> Optional[int]:
+        """Save the app's state now; returns the image step. A periodic
+        save returns None instead while a suspend writes the swap-out
+        image: it would commit a newer, lossless image of the same state
+        after it, and resume would restore that one."""
         coord = self.db.get(coord_id)
         with coord.lock:
             if coord.state not in (CoordState.RUNNING, CoordState.READY):
                 raise RuntimeError(
                     f"cannot checkpoint in state {coord.state.value}")
+            if periodic and coord_id in self._swapping_out:
+                return None
             # a gang snapshot is cut by the barrier (quiesce + drain), not
             # by reading app state under the lock — only the step number
             # is claimed here. Staged apps hand back a handle in
@@ -275,7 +285,8 @@ class AppManager:
                 if coord.state != CoordState.RUNNING:
                     continue
                 try:
-                    self.checkpoint_now(coord_id, blocking=False)
+                    self.checkpoint_now(coord_id, blocking=False,
+                                        periodic=True)
                 except Exception as e:             # noqa: BLE001
                     # state raced (RuntimeError) or the store faulted
                     # (IOError): one app's bad save must not kill the
@@ -512,18 +523,27 @@ class AppManager:
                     state = snapshot_of(coord.app, codec=swap_codec)
             step = self._step_counter.get(coord_id, 0) + 1
             self._step_counter[coord_id] = step
+            self._swapping_out.add(coord_id)
         # The blocking swap-out write runs OUTSIDE coord.lock: holding the
         # lock across a full save would stall checkpoint_now, the periodic
         # daemon and monitor-event handling for this coordinator for the
         # whole write. The snapshot above is already step-consistent (for
         # a gang job the barrier cuts it here instead — an epoch abort
         # fails the suspend with the job still RUNNING and unharmed).
-        if coord.asr.gang:
-            self._gang_snapshot(coord, step)
-        else:
-            self.ckpt.save(coord, step, state, blocking=True,
-                           metadata={"suspend": reason}, codec=swap_codec)
+        try:
+            if coord.asr.gang:
+                self._gang_snapshot(coord, step)
+            else:
+                self.ckpt.save(coord, step, state, blocking=True,
+                               metadata={"suspend": reason},
+                               codec=swap_codec)
+        except BaseException:
+            self._swapping_out.discard(coord_id)
+            raise
         with coord.lock:
+            # cleared under the lock: until SUSPENDED is published below,
+            # a periodic save would still see the job RUNNING
+            self._swapping_out.discard(coord_id)
             if coord.state != CoordState.RUNNING:
                 # a recovery/terminate won the race during the write; the
                 # image is committed and harmless, but the suspend is off

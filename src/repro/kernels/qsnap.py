@@ -26,6 +26,33 @@ from repro.ckpt import compression
 QSNAP_BLOCK = 256
 
 
+def int8_codes(x: jax.Array, scale: jax.Array) -> jax.Array:
+    """Codes of f32 ``x`` under ``scale``: the integer nearest the exact
+    quotient x / scale, ties to even, as f32 — what the host codec gets
+    from an f64 divide.
+
+    TPU f32 division need not round like IEEE division, so the quotient
+    only proposes m = floor(|x| / scale), off by at most one; whether
+    |x| lies above (m + 0.5) * scale is then decided exactly. ``scale``
+    splits into two halves of 12 significant bits, so both products with
+    m + 0.5 (8 bits) are exact, and |x| - (m + 0.5) * hi is exact wherever
+    the comparison is close (Sterbenz). Only multiply, subtract and
+    compare decide a code, and those round alike on every backend.
+    """
+    a = jnp.abs(x)
+    m = jnp.floor(a / scale)
+    half = m + 0.5
+    hi = jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(scale, jnp.int32) & jnp.int32(-4096),
+        jnp.float32)
+    above = a - half * hi
+    lo_part = half * (scale - hi)
+    odd = m - 2.0 * jnp.floor(m * 0.5) == 1.0
+    up = (above > lo_part) | ((above == lo_part) & odd)
+    c = jnp.minimum(m + up.astype(jnp.float32), 127.0)
+    return jnp.where(x < 0, -c, c)
+
+
 def _quant_kernel(x_ref, codes_ref, scales_ref):
     x = x_ref[...].astype(jnp.float32)                 # [rows, 256]
     absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
@@ -33,8 +60,7 @@ def _quant_kernel(x_ref, codes_ref, scales_ref):
     # (XLA lowers x/const to a reciprocal multiply anyway)
     scale = absmax * jnp.float32(1.0 / 127.0)
     scale = jnp.where(scale == 0, 1.0, scale)
-    codes = jnp.clip(jnp.round(x / scale), -127, 127)
-    codes_ref[...] = codes.astype(jnp.int8)
+    codes_ref[...] = int8_codes(x, scale).astype(jnp.int8)
     scales_ref[...] = scale
 
 
@@ -43,17 +69,32 @@ def _dequant_kernel(codes_ref, scales_ref, x_ref):
     x_ref[...] = (codes * scales_ref[...]).astype(x_ref.dtype)
 
 
-def _fit_block_rows(rows: int, cap: int) -> int:
-    """Largest grid tile height <= cap that divides ``rows`` evenly.
+# int8 codes tile as (32, 128) on TPU; 32 rows also satisfies f32's 8
+_ROW_TILE = 32
 
-    Leaf sizes are arbitrary (rows=300 is legal after 256-padding of a
-    76 800-element leaf), so the tile must be a true divisor — min(cap,
-    rows) alone trips the grid-coverage assert for non-power-of-two rows.
+
+def _fit_block_rows(rows: int, cap: int) -> tuple:
+    """(block_rows, padded_rows) for a grid over ``rows`` rows of 256.
+
+    Mosaic accepts a block whose row count is a multiple of the tile or
+    equals the array's whole row count. One block covers ``rows`` when it
+    fits under ``cap``; otherwise rows are padded up to a multiple of
+    ``_ROW_TILE`` and the block is the largest multiple of it, at most
+    ``cap``, that divides the padded count (a true divisor: the grid must
+    cover the array exactly).
     """
-    b = min(cap, rows)
-    while rows % b:
-        b -= 1
-    return b
+    if rows <= cap:
+        return rows, rows
+    padded = -(-rows // _ROW_TILE) * _ROW_TILE
+    b = max(_ROW_TILE, cap - cap % _ROW_TILE)
+    while padded % b:
+        b -= _ROW_TILE
+    return b, padded
+
+
+def _pad_rows(x: jax.Array, rows: int) -> jax.Array:
+    pad = rows - x.shape[0]
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
 
 
 def qsnap_quantize(x: jax.Array, *, block_rows: int = 256,
@@ -62,23 +103,24 @@ def qsnap_quantize(x: jax.Array, *, block_rows: int = 256,
     n = x.shape[0]
     assert n % QSNAP_BLOCK == 0, n
     rows = n // QSNAP_BLOCK
-    block_rows = _fit_block_rows(rows, block_rows)
-    xm = x.reshape(rows, QSNAP_BLOCK)
+    block_rows, padded = _fit_block_rows(rows, block_rows)
+    # zero pad rows quantize to scale 1, codes 0 and are sliced off below
+    xm = _pad_rows(x.reshape(rows, QSNAP_BLOCK), padded)
     codes, scales = pl.pallas_call(
         _quant_kernel,
-        grid=(rows // block_rows,),
+        grid=(padded // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, QSNAP_BLOCK), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((block_rows, QSNAP_BLOCK), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, QSNAP_BLOCK), jnp.int8),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((padded, QSNAP_BLOCK), jnp.int8),
+            jax.ShapeDtypeStruct((padded, 1), jnp.float32),
         ],
         interpret=interpret,
     )(xm)
-    return codes.reshape(-1), scales.reshape(-1)
+    return codes[:rows].reshape(-1), scales[:rows].reshape(-1)
 
 
 def qsnap_dequantize(codes: jax.Array, scales: jax.Array, dtype=jnp.float32,
@@ -86,19 +128,20 @@ def qsnap_dequantize(codes: jax.Array, scales: jax.Array, dtype=jnp.float32,
     """Inverse of qsnap_quantize -> [N] of ``dtype``."""
     n = codes.shape[0]
     rows = n // QSNAP_BLOCK
-    block_rows = _fit_block_rows(rows, block_rows)
+    block_rows, padded = _fit_block_rows(rows, block_rows)
     out = pl.pallas_call(
         _dequant_kernel,
-        grid=(rows // block_rows,),
+        grid=(padded // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, QSNAP_BLOCK), lambda i: (i, 0)),
             pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_rows, QSNAP_BLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, QSNAP_BLOCK), dtype),
+        out_shape=jax.ShapeDtypeStruct((padded, QSNAP_BLOCK), dtype),
         interpret=interpret,
-    )(codes.reshape(rows, QSNAP_BLOCK), scales.reshape(rows, 1))
-    return out.reshape(-1)
+    )(_pad_rows(codes.reshape(rows, QSNAP_BLOCK), padded),
+      _pad_rows(scales.reshape(rows, 1), padded))
+    return out[:rows].reshape(-1)
 
 
 def _encode_impl() -> str:

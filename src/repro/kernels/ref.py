@@ -12,6 +12,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.qsnap import int8_codes
+
 NEG_INF = -1e30
 QSNAP_BLOCK = 256
 
@@ -68,12 +70,13 @@ def qsnap_ref(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
 
     Returns (codes int8 [N], scales f32 [N/256]). Matches
     ``repro.ckpt.compression.quantize_int8`` bit-for-bit (both sides use
-    the absmax * (1/127) multiply — see ``compression.INV127``).
+    the absmax * (1/127) multiply — see ``compression.INV127`` — and
+    round the exact quotient, see ``qsnap.int8_codes``).
     """
     xf = x.astype(jnp.float32).reshape(-1, QSNAP_BLOCK)
     scales = jnp.max(jnp.abs(xf), axis=1) * jnp.float32(1.0 / 127.0)
     scales = jnp.where(scales == 0, 1.0, scales)
-    codes = jnp.clip(jnp.round(xf / scales[:, None]), -127, 127)
+    codes = int8_codes(xf, scales[:, None])
     return codes.astype(jnp.int8).reshape(-1), scales
 
 

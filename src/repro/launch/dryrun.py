@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any jax import: jax locks the device
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any jax import: jax locks the device
 # count at first init, and the production meshes need 512 placeholder
-# devices (2 pods x 16 x 16). Everything else imports below.
+# CPU devices (2 pods x 16 x 16) — pinned to the CPU so this process and
+# the per-cell children it starts never claim an accelerator. Everything
+# else imports below.
 
 import argparse          # noqa: E402
 import json              # noqa: E402

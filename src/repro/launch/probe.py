@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# Perf-iteration probe: compile a depth-2 unrolled cell and print the top
-# collectives + cost numbers — the dry-run equivalent of a profiler trace.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# Perf-iteration probe: compile a depth-2 unrolled cell on 512 placeholder
+# CPU devices and print the top collectives + cost numbers — the dry-run
+# equivalent of a profiler trace.
 
 import argparse      # noqa: E402
 import json          # noqa: E402

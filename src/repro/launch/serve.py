@@ -18,6 +18,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.configs import get_config, reduced
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -1,9 +1,9 @@
 """Core model layers: norms, RoPE, GQA attention, MLP variants.
 
-Everything is pure-jnp (the "reference path"): on TPU the attention inner
-loops are replaced by the Pallas kernels in ``repro.kernels`` (see
-``repro.models.transformer.ATTN_IMPL``); on CPU and for the dry-run the
-reference path is lowered by XLA directly.
+Everything is pure jnp, and XLA lowers it directly on every backend:
+attention is plain XLA today, on the TPU as on the CPU. The Pallas
+attention kernels in ``repro.kernels`` are tested against their jnp
+oracles but no model calls them.
 
 Parameters are plain pytrees of jnp arrays. Each builder also records the
 *logical dims* of every leaf (e.g. ``("embed", "q_dim")``) in a parallel

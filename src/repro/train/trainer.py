@@ -142,6 +142,8 @@ class TrainerApp:
         self._host_step = 0                  # mirrors state["step"] host-side
         self.restarts = 0
         self._started = False
+        # first train-step exception; healthy() flips False on it
+        self._failure: Optional[BaseException] = None
 
     # ---- Application protocol ------------------------------------------
     def start(self, ctx, restore_state: Optional[Any]) -> None:
@@ -154,6 +156,7 @@ class TrainerApp:
         elif self._state is None:
             self._state = init_state(self.model, jax.random.PRNGKey(self.seed))
         self._stop.clear()
+        self._failure = None
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
         self._started = True
@@ -162,13 +165,26 @@ class TrainerApp:
         clock = active_clock()
         while not self._stop.is_set() and self._host_step < self.n_steps:
             t0 = clock.now()
-            batch = self.pipeline.next()
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            new_state, metrics = self._train_step(self._state, batch)
-            loss = float(metrics["loss"])
-            # join the step OUTSIDE the lock — a concurrent snapshot
-            # capture must never wait on device work
-            new_state = jax.block_until_ready(new_state)
+            try:
+                batch = self.pipeline.next()
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                new_state, metrics = self._train_step(self._state, batch)
+                loss = float(metrics["loss"])
+                # join the step OUTSIDE the lock — a concurrent snapshot
+                # capture must never wait on device work
+                new_state = jax.block_until_ready(new_state)
+            except Exception as e:                 # noqa: BLE001
+                # A step that raises (e.g. device out of memory) ends the
+                # loop: healthy() turns False so the monitor recovers the
+                # job from its newest image, and is_done() turns True so
+                # no caller polls a dead loop forever. The stream rewinds
+                # to the failed batch, so a restart retries that batch.
+                with self._state_lock:
+                    self.pipeline.step = self._host_step
+                self._failure = e
+                registry().inc("trainer.step_failures",
+                               note=f"{type(e).__name__}: {e}")
+                return
             with self._state_lock:
                 self._state = new_state
                 self._host_step += 1         # swap + count: one atomic unit
@@ -224,6 +240,8 @@ class TrainerApp:
             materialize, step=host_step if step is None else step)
 
     def healthy(self) -> bool:
+        if self._failure is not None:
+            return False
         if not self.losses:
             return True
         return bool(np.isfinite(self.last_loss))
@@ -234,7 +252,9 @@ class TrainerApp:
             self._thread.join(timeout=60)
 
     def is_done(self) -> bool:
-        return self.current_step >= self.n_steps
+        """True once every step ran, or once a step failed (the loop is
+        gone either way; healthy() tells the two apart)."""
+        return self.current_step >= self.n_steps or self._failure is not None
 
     def progress(self) -> float:
         return self.current_step / max(self.n_steps, 1)

@@ -57,9 +57,11 @@ def make_batch(cfg, model, B, S, seed=0):
 
 
 def run_subprocess(code: str, devices: int = 8, timeout: int = 560) -> str:
-    """Run python code in a subprocess with N forced host devices."""
+    """Run python code in a subprocess with N forced host devices, pinned
+    to the CPU so the child never claims an accelerator."""
     prelude = (
         "import os\n"
+        "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
         f"os.environ['XLA_FLAGS'] = "
         f"'--xla_force_host_platform_device_count={devices}'\n")
     r = subprocess.run(

@@ -10,7 +10,7 @@ except ImportError:                        # bare env: seeded fallback
     from _hypothesis_fallback import given, settings, strategies as st
 
 from repro.ckpt import compression
-from repro.kernels import ops, ref
+from repro.kernels import ops, qsnap, ref
 
 KEY = jax.random.PRNGKey(7)
 
@@ -109,6 +109,44 @@ def test_qsnap_matches_host_codec_bitexact():
     codes_h, scales_h = compression.quantize_int8(np.asarray(x))
     np.testing.assert_array_equal(np.asarray(codes_d), codes_h)
     np.testing.assert_allclose(np.asarray(scales_d), scales_h, rtol=1e-7)
+
+
+@pytest.mark.parametrize("rows", [257, 300])
+def test_qsnap_padded_grid_matches_host_codec(rows):
+    """Row counts with no tile-aligned divisor run on a padded grid; the
+    pad rows must not leak into the codes, scales or dequantized values."""
+    x = jax.random.normal(KEY, (rows * 256,), jnp.float32) * 3
+    codes, scales = qsnap.qsnap_quantize(x, interpret=True)
+    codes_h, scales_h = compression.quantize_int8(np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(codes), codes_h)
+    np.testing.assert_array_equal(np.asarray(scales), scales_h)
+    back = qsnap.qsnap_dequantize(codes, scales, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(back), compression.dequantize_int8(codes_h, scales_h,
+                                                      rows * 256))
+
+
+def test_qsnap_rounds_near_ties_like_host_codec():
+    """Values within an ulp of (k + 0.5) * scale, where rounding the f32
+    quotient and rounding the exact quotient disagree: the kernel and the
+    jnp oracle must still give the host codec's codes."""
+    rng = np.random.default_rng(3)
+    base = rng.standard_normal((64, 256)).astype(np.float32)
+    scale = np.abs(base).max(1, keepdims=True) * compression.INV127
+    k = rng.integers(-127, 127, base.shape).astype(np.float32)
+    x = ((k + 0.5) * scale).astype(np.float32)
+    step = rng.integers(-1, 2, x.shape)
+    x = np.where(step == 0, x, np.nextafter(
+        x, np.where(step > 0, np.inf, -np.inf).astype(np.float32)))
+    x[:, 0] = np.abs(base).max(1)                # keep each block's absmax
+    x = x.reshape(-1)
+    codes_h, scales_h = compression.quantize_int8(x)
+    f32_codes = np.rint(x.reshape(-1, 256) / scales_h[:, None]).reshape(-1)
+    assert (f32_codes != codes_h).sum() > 100, "data must hit double rounding"
+    for codes, scales in (qsnap.qsnap_quantize(jnp.asarray(x), interpret=True),
+                          ref.qsnap_ref(jnp.asarray(x))):
+        np.testing.assert_array_equal(np.asarray(codes), codes_h)
+        np.testing.assert_array_equal(np.asarray(scales), scales_h)
 
 
 @settings(max_examples=25, deadline=None)

@@ -190,3 +190,32 @@ def test_suspend_uses_swap_codec_and_resumes():
         assert coord.app.healthy()
     finally:
         svc.shutdown()
+
+
+def test_no_periodic_image_lands_after_the_swap_out_image():
+    """Regression: a periodic save that started while a suspend wrote its
+    swap-out image committed a newer lossless image of the same state, so
+    resume restored that one instead of the swap-out image."""
+    svc = CACSService({"snooze": SnoozeBackend(4)},
+                      {"default": InMemoryStore(latency_s=0.05)})
+    try:
+        asr = ASR(name="train", n_vms=1, backend="snooze",
+                  app_factory=lambda: TrainerApp(CFG, global_batch=2,
+                                                 seq_len=16, n_steps=2),
+                  policy=CheckpointPolicy(period_s=0.05, codec="raw",
+                                          keep_last=0, swap_codec="int8"))
+        cid = svc.submit(asr)
+        svc.wait_for_state(cid, CoordState.RUNNING, 60)
+        coord = svc.db.get(cid)
+        while not coord.app.is_done():
+            time.sleep(0.02)
+        svc.apps.suspend(cid)
+        time.sleep(0.5)                      # let a racing save get queued
+        svc.apps.ckpt.wait(coord)
+        newest = svc.list_checkpoints(cid)[-1]
+        assert svc.get_checkpoint(cid, newest)["metadata"]["suspend"] \
+            == "user"
+        svc.apps.resume(cid)
+        assert svc.db.get(cid).state == CoordState.RUNNING
+    finally:
+        svc.shutdown()

@@ -10,6 +10,7 @@ import pytest
 from repro.ckpt import InMemoryStore, restore, save_checkpoint
 from repro.configs import get_config, reduced
 from repro.data.pipeline import TokenPipeline
+from repro.obs.telemetry import registry
 from repro.train import (AdamWConfig, TrainerApp, adamw_init, adamw_update,
                          lr_at)
 
@@ -120,6 +121,40 @@ def test_bit_exact_resume_through_checkpoint():
         time.sleep(0.02)
     resumed.stop()
     assert resumed.losses[-1] == straight.losses[-1], "resume not bit-exact"
+
+
+def test_failed_step_ends_loop_and_flips_health():
+    """Regression: a raising train step used to kill the loop thread with
+    is_done() never true, hanging every `while not app.is_done()` caller.
+    It must be counted, flip healthy(), end is_done(), and a restart must
+    retry the failed batch."""
+    before = registry().value("trainer.step_failures", 0.0)
+    app = TrainerApp(CFG, global_batch=2, seq_len=16, n_steps=6)
+    real_step = app._train_step
+    calls = []
+
+    def step(state, batch):
+        calls.append(1)
+        if len(calls) == 3:
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+        return real_step(state, batch)
+    app._train_step = step
+    app.start(None, None)
+    app._thread.join(timeout=60)
+    assert not app._thread.is_alive(), "loop must end on a failed step"
+    assert app.current_step == 2
+    assert app.is_done() and not app.healthy()
+    assert registry().value("trainer.step_failures", 0.0) == before + 1
+    assert app.pipeline.step == 2, "stream must rewind to the failed batch"
+    app.stop()
+
+    app.start(None, None)                   # in-place restart retries it
+    app._thread.join(timeout=60)
+    assert app.current_step == 6 and app.healthy()
+    straight = TrainerApp(CFG, global_batch=2, seq_len=16, n_steps=6)
+    straight.start(None, None)
+    straight._thread.join(timeout=60)
+    assert app.losses == straight.losses
 
 
 def test_health_hook_detects_nan():
