@@ -27,6 +27,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
+from repro.obs.trace import tracer
+
 try:
     import ml_dtypes
 except ImportError:                               # pragma: no cover
@@ -215,7 +217,8 @@ def local_shards(arr) -> List[Tuple[Tuple[int, ...], Tuple[int, ...],
                                     np.ndarray]]:
     """Unique addressable shards of a jax.Array (replicas deduped).
 
-    Returns [(offset, shape, host_ndarray)].
+    Returns [(offset, shape, host_ndarray)]. Each device-to-host copy is
+    one ``ckpt/d2h`` span.
     """
     if not isinstance(arr, jax.Array):
         a = np.asarray(arr)
@@ -229,5 +232,8 @@ def local_shards(arr) -> List[Tuple[Tuple[int, ...], Tuple[int, ...],
         if off in seen:
             continue
         seen.add(off)
-        out.append((off, shp, np.asarray(sh.data)))
+        with tracer().span("ckpt/d2h", cat="ckpt",
+                           args={"nbytes": sh.data.nbytes, "shape": shp}):
+            host = np.asarray(sh.data)
+        out.append((off, shp, host))
     return out

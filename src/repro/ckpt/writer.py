@@ -227,7 +227,9 @@ class _Encoded:
 
 def _encode_chunk(ctx: _SaveContext, step: int, name: str, off, shp,
                   host, dtype: str) -> _Encoded:
-    """Stage 1: serialize + codec + digest (CPU-bound, encode pool).
+    """Stage 1: serialize + codec + digest (CPU-bound, encode pool), each
+    a child span of ``ckpt/encode`` (``ckpt/serialize``, ``ckpt/codec``,
+    ``ckpt/digest`` with ``of`` "raw" or "chunk").
 
     ``host`` is a host ndarray, or a PreEncodedChunk whose payload was
     built on device — then the codec is already applied and this stage
@@ -239,23 +241,34 @@ def _encode_chunk(ctx: _SaveContext, step: int, name: str, off, shp,
         return _encode_chunk_inner(ctx, step, name, off, shp, host, dtype)
 
 
+def _codec(fn, *args) -> bytes:
+    with tracer().span("ckpt/codec", cat="ckpt"):
+        return fn(*args)
+
+
+def _digest(of: str, fn, *args) -> str:
+    with tracer().span("ckpt/digest", cat="ckpt", args={"of": of}):
+        return fn(*args)
+
+
 def _encode_chunk_inner(ctx: _SaveContext, step: int, name: str, off, shp,
                         host, dtype: str) -> _Encoded:
     if isinstance(host, PreEncodedChunk):
-        data = _adapt_pre_encoded(host, ctx.codec)
+        data = _codec(_adapt_pre_encoded, host, ctx.codec)
         if not ctx.incremental:
             return _Encoded(key=chunk_key(ctx.prefix, step, name, off),
                             data=data, off=off, shp=shp)
-        return _Encoded(digest=chunk_digest(data), data=data, off=off,
-                        shp=shp)
-    raw = np.ascontiguousarray(host).tobytes()
+        return _Encoded(digest=_digest("chunk", chunk_digest, data),
+                        data=data, off=off, shp=shp)
+    with tracer().span("ckpt/serialize", cat="ckpt"):
+        raw = np.ascontiguousarray(host).tobytes()
     if not ctx.incremental:
         key = chunk_key(ctx.prefix, step, name, off)
-        data = compression.encode(raw, host.dtype, ctx.codec)
+        data = _codec(compression.encode, raw, host.dtype, ctx.codec)
         return _Encoded(key=key, data=data, off=off, shp=shp)
     rk: Optional[str] = None
     if ctx.raw_cache is not None:
-        rk = _raw_digest(ctx.codec, dtype, raw)
+        rk = _digest("raw", _raw_digest, ctx.codec, dtype, raw)
         if not ctx.raw_flight.claim(rk, lambda: rk in ctx.raw_cache):
             with ctx.lock:
                 digest, nbytes = ctx.raw_cache[rk]
@@ -264,13 +277,13 @@ def _encode_chunk_inner(ctx: _SaveContext, step: int, name: str, off, shp,
                 off, shp, cas_key(ctx.prefix, ctx.cas_scope + digest),
                 nbytes, digest))
     try:
-        data = compression.encode(raw, host.dtype, ctx.codec)
+        data = _codec(compression.encode, raw, host.dtype, ctx.codec)
     except BaseException:
         if rk is not None:
             ctx.raw_flight.abort(rk)             # let a waiter retry
         raise
-    return _Encoded(digest=chunk_digest(data), data=data, raw_key=rk,
-                    off=off, shp=shp)
+    return _Encoded(digest=_digest("chunk", chunk_digest, data), data=data,
+                    raw_key=rk, off=off, shp=shp)
 
 
 def _upload_chunk(ctx: _SaveContext, enc: _Encoded) -> ChunkInfo:
@@ -462,7 +475,6 @@ class AsyncCheckpointer:
         self._lock = threading.Lock()
         self.last_committed: Optional[int] = None
         self.save_count = 0
-        self.staging_time = 0.0
         self._known: Optional[Dict[str, int]] = None
         self._raw_cache: Dict[str, Tuple[str, int]] = {}
         # cumulative dedup counters across saves (read via stats())
@@ -490,7 +502,6 @@ class AsyncCheckpointer:
         # not poison this independent save: record it and move on. The
         # failed step has no COMMITTED marker, so it is simply invisible.
         self.wait(raise_error=False)
-        t0 = time.monotonic()
         if isinstance(tree, SnapshotHandle):
             staged = skeleton = None               # resolved on writer thread
         else:
@@ -499,7 +510,6 @@ class AsyncCheckpointer:
                                args={"step": step}):
                 staged = _stage(tree)              # sync: consistent snapshot
                 skeleton = structure_skeleton(tree)
-        self.staging_time += time.monotonic() - t0
         save_codec = codec or self.codec
 
         def job():

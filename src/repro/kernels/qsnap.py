@@ -22,6 +22,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.ckpt import compression
+from repro.obs.trace import tracer
 
 QSNAP_BLOCK = 256
 
@@ -172,8 +173,12 @@ def qsnap_encode_chunks(arrs: Sequence[jax.Array], *,
     payloads: List[Optional[bytes]] = [None] * len(arrs)
     for i, arr in enumerate(arrs):
         if not compression.is_float_dtype(np.dtype(arr.dtype)):
+            with tracer().span("ckpt/d2h", cat="ckpt",
+                               args={"nbytes": arr.nbytes,
+                                     "shape": tuple(arr.shape)}):
+                host = jax.device_get(arr)
             payloads[i] = compression.frame_raw(
-                np.ascontiguousarray(jax.device_get(arr)).tobytes())
+                np.ascontiguousarray(host).tobytes())
             continue
         flat = arr.reshape(-1)
         n = flat.size
@@ -188,7 +193,11 @@ def qsnap_encode_chunks(arrs: Sequence[jax.Array], *,
                                            interpret=interpret)
         staged.append((i, n, codes, scales))
     if staged:
-        fetched = jax.device_get([(c, s) for _, _, c, s in staged])
+        pairs = [(c, s) for _, _, c, s in staged]
+        with tracer().span("ckpt/d2h", cat="ckpt", args={
+                "nbytes": sum(c.nbytes + s.nbytes for c, s in pairs),
+                "arrays": len(pairs)}):
+            fetched = jax.device_get(pairs)
         for (i, n, _, _), (codes, scales) in zip(staged, fetched):
             payloads[i] = compression.frame_int8(n, scales, codes)
     return payloads  # type: ignore[return-value]

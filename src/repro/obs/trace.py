@@ -9,6 +9,14 @@ nesting on one thread is automatic (thread-local stack), and work handed
 to pool threads passes ``parent=`` explicitly (the writer/reader pipelines
 do this for per-chunk encode/upload/fetch spans).
 
+Every span an enabled tracer records is also a
+``jax.profiler.TraceAnnotation`` of the same name, opened and closed on
+the span's thread, with the span's scalar args as its metadata: under
+``jax.profiler.trace`` the span lands in the ``.xplane.pb`` on that
+thread's ``/host:`` line, on the profiler's clock, beside the device's
+operations. With no profile active an annotation costs about a
+microsecond.
+
 Exports:
 
   * ``export_jsonl`` — one JSON object per line, self-contained.
@@ -35,6 +43,8 @@ import threading
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.sim.simtime import active_clock
 
 __all__ = ["Span", "Tracer", "tracer", "install_tracer", "use_tracer"]
@@ -43,6 +53,16 @@ __all__ = ["Span", "Tracer", "tracer", "install_tracer", "use_tracer"]
 def _paper_now() -> float:
     clk = active_clock()
     return clk.now() / clk.scale
+
+
+def _annotation(name: str, args: Optional[Dict[str, Any]]
+                ) -> TraceAnnotation:
+    """The profiler annotation of a span: its name, and its str, int and
+    float args as metadata."""
+    if not args:
+        return TraceAnnotation(name)
+    return TraceAnnotation(name, **{
+        k: v for k, v in args.items() if isinstance(v, (str, int, float))})
 
 
 class Span:
@@ -131,7 +151,8 @@ class Tracer:
             stack = self._tls.stack = []
         stack.append(sp)
         try:
-            yield sp
+            with _annotation(name, args):
+                yield sp
         except BaseException as exc:
             sp.args.setdefault("error", type(exc).__name__)
             raise

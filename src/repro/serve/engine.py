@@ -18,6 +18,7 @@ from repro.ckpt.snapshot import DeferredSnapshot, SnapshotHandle
 from repro.configs.base import ArchConfig
 from repro.models.model import Model, build_model
 from repro.obs.telemetry import SampleView, registry, unique_name
+from repro.obs.trace import tracer
 from repro.sim.simtime import active_clock
 
 
@@ -132,35 +133,44 @@ class ServeApp:
         while not self._stop.is_set() and self.generated < self.n_tokens:
             if self.token_delay_s:
                 clock.sleep(self.token_delay_s)
-            pos = jnp.int32(self.prompt_len + self.generated - 1)
-            # NOTE: cache is donated; keep the swap atomic wrt checkpointing
-            with self._lock:
-                cache, token = self.cache, self._last_token
-                self.cache = None
-            try:
-                logits, new_cache = self.engine.decode(cache, token, pos)
-            except BaseException as e:             # noqa: BLE001
-                # Restore the surrendered slot: leaving it None would make
-                # every _capture (snapshot_async, suspend) block forever on
-                # a dead loop. The pre-decode cache is the last consistent
-                # state (best-effort — if the jitted call got far enough to
-                # consume the donated buffer, a later restore re-reads the
-                # newest committed image instead), so a suspend issued
-                # after the fault still swaps out cleanly.
+            tr = tracer()
+            with tr.span("serve/step", cat="serve",
+                         args={"generated": self.generated}):
+                pos = jnp.int32(self.prompt_len + self.generated - 1)
+                # NOTE: cache is donated; keep the swap atomic wrt
+                # checkpointing
+                with self._lock:
+                    cache, token = self.cache, self._last_token
+                    self.cache = None
+                with tr.span("serve/dispatch", cat="serve"):
+                    try:
+                        logits, new_cache = self.engine.decode(cache, token,
+                                                               pos)
+                    except BaseException as e:     # noqa: BLE001
+                        # Restore the surrendered slot: leaving it None
+                        # would make every _capture (snapshot_async,
+                        # suspend) block forever on a dead loop. The
+                        # pre-decode cache is the last consistent state
+                        # (best-effort — if the jitted call got far enough
+                        # to consume the donated buffer, a later restore
+                        # re-reads the newest committed image instead), so
+                        # a suspend issued after the fault still swaps out
+                        # cleanly.
+                        with self._cond:
+                            self.cache = cache
+                            self._failure = e
+                            self._cond.notify_all()
+                        registry().inc("serve.decode_failures",
+                                       note=f"{type(e).__name__}: {e}")
+                        return
+                    token = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
                 with self._cond:
-                    self.cache = cache
-                    self._failure = e
+                    with tr.span("serve/sync", cat="serve"):
+                        self.cache = jax.block_until_ready(new_cache)
+                        self._last_token = token
+                        self.tokens_out.append(np.asarray(token))
+                    self.generated += 1
                     self._cond.notify_all()
-                registry().inc("serve.decode_failures",
-                               note=f"{type(e).__name__}: {e}")
-                return
-            token = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-            with self._cond:
-                self.cache = jax.block_until_ready(new_cache)
-                self._last_token = token
-                self.tokens_out.append(np.asarray(token))
-                self.generated += 1
-                self._cond.notify_all()
 
     def _capture(self) -> Dict[str, Any]:
         """Pin a consistent snapshot under the lock (waits out the window

@@ -24,6 +24,7 @@ from repro.ckpt.snapshot import DeferredSnapshot, SnapshotHandle
 from repro.configs.base import ArchConfig
 from repro.data.pipeline import TokenPipeline
 from repro.obs.telemetry import SampleView, registry, unique_name
+from repro.obs.trace import tracer
 from repro.kernels.qsnap import qsnap_encode_chunks
 from repro.models.model import Model, build_model
 from repro.sharding.specs import MeshAxes, activation_sharding
@@ -164,33 +165,40 @@ class TrainerApp:
     def _run(self) -> None:
         clock = active_clock()
         while not self._stop.is_set() and self._host_step < self.n_steps:
-            t0 = clock.now()
-            try:
-                batch = self.pipeline.next()
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
-                new_state, metrics = self._train_step(self._state, batch)
-                loss = float(metrics["loss"])
-                # join the step OUTSIDE the lock — a concurrent snapshot
-                # capture must never wait on device work
-                new_state = jax.block_until_ready(new_state)
-            except Exception as e:                 # noqa: BLE001
-                # A step that raises (e.g. device out of memory) ends the
-                # loop: healthy() turns False so the monitor recovers the
-                # job from its newest image, and is_done() turns True so
-                # no caller polls a dead loop forever. The stream rewinds
-                # to the failed batch, so a restart retries that batch.
+            tr = tracer()
+            with tr.span("train/step", cat="train"):
+                t0 = clock.now()
+                try:
+                    with tr.span("train/input", cat="train"):
+                        batch = self.pipeline.next()
+                        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                    with tr.span("train/dispatch", cat="train"):
+                        new_state, metrics = self._train_step(self._state,
+                                                              batch)
+                    with tr.span("train/sync", cat="train"):
+                        loss = float(metrics["loss"])
+                        # join the step OUTSIDE the lock — a concurrent
+                        # snapshot capture must never wait on device work
+                        new_state = jax.block_until_ready(new_state)
+                except Exception as e:             # noqa: BLE001
+                    # A step that raises (e.g. device out of memory) ends
+                    # the loop: healthy() turns False so the monitor
+                    # recovers the job from its newest image, and
+                    # is_done() turns True so no caller polls a dead loop
+                    # forever. The stream rewinds to the failed batch, so
+                    # a restart retries that batch.
+                    with self._state_lock:
+                        self.pipeline.step = self._host_step
+                    self._failure = e
+                    registry().inc("trainer.step_failures",
+                                   note=f"{type(e).__name__}: {e}")
+                    return
                 with self._state_lock:
-                    self.pipeline.step = self._host_step
-                self._failure = e
-                registry().inc("trainer.step_failures",
-                               note=f"{type(e).__name__}: {e}")
-                return
-            with self._state_lock:
-                self._state = new_state
-                self._host_step += 1         # swap + count: one atomic unit
-            self.last_loss = loss
-            self.losses.append(loss)
-            self.step_times.append(clock.now() - t0)
+                    self._state = new_state
+                    self._host_step += 1     # swap + count: one atomic unit
+                self.last_loss = loss
+                self.losses.append(loss)
+                self.step_times.append(clock.now() - t0)
 
     @property
     def ckpt_stalls(self) -> "SampleView":
