@@ -381,7 +381,10 @@ class AppManager:
             return coord.state != CoordState.RESTARTING
 
     def _recover(self, coord_id: str, kind: str) -> None:
-        coord = self.db.get(coord_id)
+        try:
+            coord = self.db.get(coord_id)
+        except KeyError:
+            return                      # a terminate removed it meanwhile
         with coord.lock:
             if coord.state != CoordState.RUNNING:
                 return                              # debounce duplicates

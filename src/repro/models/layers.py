@@ -17,6 +17,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
+
+from repro.sharding.specs import active_axis_size, constrain, sharding_active
 
 Params = Any
 Dims = Any
@@ -254,10 +257,58 @@ def attn_prefill(p: Params, spec: AttnSpec, x: jax.Array, *,
     return x + _out_proj(out, p["wo"]), {"k": k, "v": v}
 
 
+# positions rewritten around the decoded one (see _write_row)
+_ROW_CHUNK = 8
+
+
+def _write_row(buf: jax.Array, row: jax.Array, layer: jax.Array,
+               pos: jax.Array) -> jax.Array:
+    """Write ``row`` [B,1,Hkv,hd] at (layer, :, pos) of the stacked cache
+    ``buf`` [L,B,S,Hkv,hd].
+
+    The write rewrites the aligned chunk of ``_ROW_CHUNK`` positions that
+    holds ``pos``, the others with the values they already hold. A
+    one-position update leads XLA's TPU layout assignment to store the
+    carried cache with the written row contiguous, which pads a small
+    minor dim (a sharded or 64-wide head_dim) up to 128 lanes and
+    brackets the layer loop with whole-cache relayouts; a chunk keeps
+    the layout the cache was given.
+    """
+    w = math.gcd(buf.shape[2], _ROW_CHUNK)
+    start = pos // w * w
+    at = (layer, 0, start, 0, 0)
+    chunk = jax.lax.dynamic_slice(
+        buf, at, (1, buf.shape[1], w) + buf.shape[3:])
+    hit = (jnp.arange(w) == pos - start)[None, None, :, None, None]
+    chunk = jnp.where(hit, row.astype(buf.dtype)[None], chunk)
+    return _stored_layout(jax.lax.dynamic_update_slice(buf, chunk, at))
+
+
+def _stored_layout(x: jax.Array) -> jax.Array:
+    """Hold the decode's stacked cache in row-major layout, as the TPU
+    stores it when head_dim fills whole 128-lane rows. Without it XLA
+    transposes the cache to suit the attention's dots: a relayout of the
+    whole cache on the way into the layer loop and again on the way out,
+    every token. A narrower head_dim is stored with positions minor, and
+    pinning row-major would itself relayout it. Under an SPMD context
+    the partitioner replicates a value that passes through a layout
+    constraint (a whole-cache all-gather), so there XLA keeps the
+    choice."""
+    if sharding_active() or x.shape[-1] % 128:
+        return x
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+
 def attn_decode(p: Params, spec: AttnSpec, x: jax.Array,
                 cache: Dict[str, jax.Array], pos: jax.Array,
-                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode. x: [B,1,d]; cache k/v: [B,S_max,Hkv,hd]; pos scalar."""
+                layer: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One-token decode. x: [B,1,d]; cache k/v: the whole stack's
+    [L,B,S_max,Hkv,hd]; pos, layer scalars.
+
+    Writes the token's k/v at (layer, :, pos) and returns the stack's
+    updated cache: under donation XLA updates the buffer in place, and
+    only this layer's slice is read.
+    """
     B = x.shape[0]
     positions = jnp.full((B, 1), pos, dtype=jnp.int32)
     h = rmsnorm(x, p["norm"], spec.norm_eps)
@@ -265,7 +316,6 @@ def attn_decode(p: Params, spec: AttnSpec, x: jax.Array,
     if spec.use_rope:
         q = apply_rope(q, positions, spec.rope_theta)
         k = apply_rope(k, positions, spec.rope_theta)
-    from repro.sharding.specs import active_axis_size, constrain
     tp = active_axis_size("tp")
     if tp > 1 and spec.n_kv_heads % tp != 0 and spec.head_dim % tp == 0:
         # KV cache is head_dim-sharded (kv_heads don't divide TP). Align
@@ -275,14 +325,14 @@ def attn_decode(p: Params, spec: AttnSpec, x: jax.Array,
         q = constrain(q, ("dp", None, None, "tp"))
         k = constrain(k, ("dp", None, None, "tp"))
         v = constrain(v, ("dp", None, None, "tp"))
-    ck = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k.astype(cache["k"].dtype), pos, axis=1)
-    cv = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v.astype(cache["v"].dtype), pos, axis=1)
-    kv_positions = jnp.arange(ck.shape[1])
+    ck = _write_row(cache["k"], k, layer, pos)
+    cv = _write_row(cache["v"], v, layer, pos)
     # slots beyond pos are masked by the causal relation on positions
-    out = attention_ref(q, ck, cv, causal=True, window=spec.window,
-                        q_positions=positions[0], kv_positions=kv_positions)
+    ck_l = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+    cv_l = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+    out = attention_ref(q, ck_l, cv_l, causal=True, window=spec.window,
+                        q_positions=positions[0],
+                        kv_positions=jnp.arange(ck_l.shape[1]))
     return x + _out_proj(out, p["wo"]), {"k": ck, "v": cv}
 
 

@@ -273,50 +273,56 @@ def stack_prefill(params_stack: Params, blocks: List[Block], x: jax.Array,
     return jax.lax.scan(body, x, params_stack)
 
 
+_RECURRENT_DECODE = {"mamba": S.mamba_decode, "mlstm": X.mlstm_decode,
+                     "slstm": X.slstm_decode}
+
+
 def stack_decode(params_stack: Params, blocks: List[Block], x: jax.Array,
                  cache_stack: Params, pos: jax.Array, *,
                  unroll: bool = False) -> Tuple[jax.Array, Params]:
-    """One-token decode through the stack. x: [B,1,d]."""
+    """One-token decode through the stack. x: [B,1,d].
 
-    def body(x, inp):
-        p_g, c_g = inp
-        new_c: Dict[str, Any] = {}
+    The stacked cache rides in the carry, not in the scan's xs/ys: each
+    layer writes its own row (attention: position ``pos``; recurrent
+    blocks: their state) into the whole buffer, so a donated cache is
+    updated in place and no layer's slice is copied out and back.
+    """
+
+    def body(carry, inp):
+        x, cache = carry
+        i, p_g = inp
+        cache = dict(cache)
         for blk in blocks:
             p = p_g[blk.name]
             if blk.kind == "attn":
-                x, c = L.attn_decode(p, blk.spec, x, c_g[blk.name], pos)
-                new_c[blk.name] = c
+                x, cache[blk.name] = L.attn_decode(
+                    p, blk.spec, x, cache[blk.name], pos, i)
             elif blk.kind == "cross_attn":
-                mem = (c_g[blk.name]["mk"], c_g[blk.name]["mv"])
+                c = cache[blk.name]
+                mem = (c["mk"][i], c["mv"][i])
                 x = L.cross_attn_decode(p, blk.spec, x, mem)
-                new_c[blk.name] = c_g[blk.name]
             elif blk.kind == "mlp":
                 x = L.mlp_apply(p, blk.spec, x)
             elif blk.kind == "moe":
                 x, _ = M.moe_apply(p, blk.spec, x)
-            elif blk.kind == "mamba":
-                x, c = S.mamba_decode(p, blk.spec, x, c_g[blk.name])
-                new_c[blk.name] = c
-            elif blk.kind == "mlstm":
-                x, c = X.mlstm_decode(p, blk.spec, x, c_g[blk.name])
-                new_c[blk.name] = c
-            elif blk.kind == "slstm":
-                x, c = X.slstm_decode(p, blk.spec, x, c_g[blk.name])
-                new_c[blk.name] = c
-        return x, new_c
+            elif blk.kind in _RECURRENT_DECODE:
+                c = cache[blk.name]
+                x, c_i = _RECURRENT_DECODE[blk.kind](
+                    p, blk.spec, x, jax.tree.map(lambda t: t[i], c))
+                cache[blk.name] = jax.tree.map(
+                    lambda t, u: jax.lax.dynamic_update_index_in_dim(
+                        t, u.astype(t.dtype), i, 0), c, c_i)
+        return (x, cache), None
 
+    carry = (x, cache_stack)
+    n = jax.tree.leaves(params_stack)[0].shape[0]
     if unroll:
-        n = jax.tree.leaves(params_stack)[0].shape[0]
-        caches = []
         for i in range(n):
-            inp = jax.tree.map(lambda t, i=i: t[i],
-                               (params_stack, cache_stack))
-            x, c = body(x, inp)
-            caches.append(c)
-        new_cache = jax.tree.map(lambda *ts: jnp.stack(ts), *caches)
-        return x, new_cache
-    x, new_cache = jax.lax.scan(body, x, (params_stack, cache_stack))
-    return x, new_cache
+            p_g = jax.tree.map(lambda t, i=i: t[i], params_stack)
+            carry, _ = body(carry, (i, p_g))
+        return carry
+    carry, _ = jax.lax.scan(body, carry, (jnp.arange(n), params_stack))
+    return carry
 
 
 # ---------------------------------------------------------------------------

@@ -153,6 +153,11 @@ def active_axis_size(kind: str) -> int:
     return math.prod(_ACTIVE.size(a) for a in ax_t)
 
 
+def sharding_active() -> bool:
+    """Whether an ``activation_sharding`` context is active."""
+    return _ACTIVE is not None
+
+
 @contextlib.contextmanager
 def activation_sharding(axes: Optional[MeshAxes]):
     global _ACTIVE

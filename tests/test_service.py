@@ -100,6 +100,20 @@ def test_app_failure_restarts_in_place(snooze_svc):
     assert c.app.iteration > 0        # restored from checkpoint, not zero
 
 
+def test_recovery_event_after_terminate_is_dropped(snooze_svc):
+    """A monitor event for a coordinator that a terminate has removed (a
+    poll in flight when it was unwatched) is dropped, not counted as a
+    failed operation."""
+    from repro.obs.telemetry import registry
+    svc, _ = snooze_svc
+    cid = _submit(svc, "snooze")
+    svc.delete_coordinator(cid)
+    before = registry().value("appmgr.op_errors", 0.0)
+    svc.apps._submit_once(cid, svc.apps._recover, cid,
+                          "vm_failure").result(timeout=10)
+    assert registry().value("appmgr.op_errors", 0.0) == before
+
+
 def test_recovery_restores_latest_state(snooze_svc):
     svc, backend = snooze_svc
     cid = _submit(svc, "snooze")
