@@ -22,6 +22,7 @@ from repro.configs.internvl2_2b import CONFIG as INTERNVL2_2B
 from repro.configs.llama4_maverick_400b_a17b import CONFIG as LLAMA4_MAVERICK
 from repro.configs.llama4_scout_17b_a16e import CONFIG as LLAMA4_SCOUT
 from repro.configs.jamba_v0_1_52b import CONFIG as JAMBA_V0_1_52B
+from repro.configs.jamba2_3b import CONFIG as JAMBA2_3B
 from repro.configs.repro_100m import CONFIG as REPRO_100M
 
 ARCH_REGISTRY = {
@@ -36,6 +37,7 @@ ARCH_REGISTRY = {
         LLAMA4_MAVERICK,
         LLAMA4_SCOUT,
         JAMBA_V0_1_52B,
+        JAMBA2_3B,
         REPRO_100M,
     )
 }
@@ -67,6 +69,8 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
     if cfg.attn_pattern == "local_global":
         changes["n_layers"] = cfg.local_global_ratio + 1  # one local:global group
         changes["local_window"] = 8
+    if cfg.ssm is not None:                           # dt_rank follows d_model
+        changes["ssm"] = dataclasses.replace(cfg.ssm, dt_rank=None)
     if cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe, num_experts=min(cfg.moe.num_experts, 4), d_ff=256)

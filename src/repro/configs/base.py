@@ -27,6 +27,12 @@ class SSMConfig:
     d_state: int = 16
     d_conv: int = 4
     expand: int = 2              # d_inner = expand * d_model
+    dt_rank: Optional[int] = None    # default: ceil(d_model / 16)
+    # Jamba: RMSNorms on dt, B and C after x_proj (arXiv:2403.19887)
+    inner_norms: bool = False
+
+    def rank(self, d_model: int) -> int:
+        return self.dt_rank or -(-d_model // 16)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,8 +73,12 @@ class ArchConfig:
     local_window: int = 1024
     local_global_ratio: int = 0              # e.g. 5 => 5 local : 1 global
 
-    # Hybrid attention:ssm interleave (jamba): 1 attn per `attn_every` layers.
+    # Hybrid attention:ssm interleave (jamba): 1 attn per `attn_every`
+    # layers, at layer `attn_offset` of each period.
     attn_every: int = 1
+    attn_offset: int = 0
+    # False: attention without positional encoding (jamba)
+    use_rope: bool = True
 
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -102,6 +112,11 @@ class ArchConfig:
     def q_per_kv(self) -> int:
         return self.n_heads // self.n_kv_heads
 
+    def is_attn_layer(self, i: int) -> bool:
+        """Layer ``i`` mixes tokens by attention (else by its recurrent
+        block, in a hybrid)."""
+        return self.attn_every <= 1 or i % self.attn_every == self.attn_offset
+
     def param_count(self) -> int:
         """Analytic total parameter count (embeddings included)."""
         d, hd = self.d_model, self.head_dim
@@ -112,7 +127,7 @@ class ArchConfig:
 
         total = 0
         for i in range(self.n_layers):
-            is_attn = (i % self.attn_every) == 0 if self.attn_every > 1 else True
+            is_attn = self.is_attn_layer(i)
             if self.xlstm is not None:
                 dm = int(self.xlstm.proj_factor * d)
                 total += 2 * d * dm + dm * d + 4 * d * dm  # rough mLSTM/sLSTM
@@ -120,8 +135,12 @@ class ArchConfig:
             if is_attn:
                 total += attn
             elif self.ssm is not None:
-                di = self.ssm.expand * d
-                total += 2 * d * di + di * d + di * (2 * self.ssm.d_state + 2)
+                di, N = self.ssm.expand * d, self.ssm.d_state
+                R, W = self.ssm.rank(d), self.ssm.d_conv
+                total += (3 * d * di + di * (R + 2 * N) + R * di
+                          + di * (W + N + 3))
+                if self.ssm.inner_norms:
+                    total += R + 2 * N
             if self.moe is not None and (i % self.moe.every) == (self.moe.every - 1):
                 total += self.moe.num_experts * ffn_params(self.moe.d_ff)
                 total += d * self.moe.num_experts  # router

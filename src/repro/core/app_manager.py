@@ -50,6 +50,15 @@ def progress_counter(app: Any) -> Optional[Callable[[], float]]:
     return None
 
 
+def _note_pinned_bytes(span, app: Any) -> None:
+    """Put the bytes of each kind of state the pin copied on the device
+    on its span (``attn_bytes``, ``ssm_bytes``), for apps that report them
+    (a server's ``cache_bytes``)."""
+    fn = getattr(app, "cache_bytes", None)
+    for kind, n in (fn() if fn is not None else {}).items():
+        span.set(f"{kind}_bytes", n)
+
+
 class AppManager:
     def __init__(self, db: CoordinatorDB, cloud: CloudManager,
                  provision: ProvisionManager, ckpt: CheckpointManager,
@@ -242,8 +251,9 @@ class AppManager:
                 state = None
             else:
                 with tracer().span("ckpt/pin", cat="ckpt",
-                                   trace_id=coord.trace_id):
+                                   trace_id=coord.trace_id) as pin:
                     state = snapshot_of(coord.app)
+                    _note_pinned_bytes(pin, coord.app)
             # claim the step under the lock: a concurrent suspend (or a
             # second checkpoint_now) must not mint the same step number
             step = self._step_counter.get(coord_id, 0) + 1
@@ -522,8 +532,9 @@ class AppManager:
             else:
                 with tracer().span("ckpt/pin", cat="ckpt",
                                    trace_id=coord.trace_id,
-                                   args={"suspend": reason}):
+                                   args={"suspend": reason}) as pin:
                     state = snapshot_of(coord.app, codec=swap_codec)
+                    _note_pinned_bytes(pin, coord.app)
             step = self._step_counter.get(coord_id, 0) + 1
             self._step_counter[coord_id] = step
             self._swapping_out.add(coord_id)

@@ -1,9 +1,11 @@
 """Core model layers: norms, RoPE, GQA attention, MLP variants.
 
 Everything is pure jnp, and XLA lowers it directly on every backend:
-attention is plain XLA today, on the TPU as on the CPU. The Pallas
-attention kernels in ``repro.kernels`` are tested against their jnp
-oracles but no model calls them.
+attention is plain XLA today, on the TPU as on the CPU. The prefill runs
+it over blocks of queries (``attention_blocked``), so its scores never
+span the whole prompt twice. The Pallas attention kernels in
+``repro.kernels`` are tested against their jnp oracles but no model
+calls them.
 
 Parameters are plain pytrees of jnp arrays. Each builder also records the
 *logical dims* of every leaf (e.g. ``("embed", "q_dim")``) in a parallel
@@ -45,6 +47,17 @@ class ParamBuilder:
             p = jnp.zeros(shape, self.dtype)
         elif init == "ones":
             p = jnp.ones(shape, self.dtype)
+        elif init == "s4d_real":
+            # Mamba's A_log: log(1..N) along the last (state) axis
+            n = jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)
+            p = jnp.broadcast_to(jnp.log(n), shape).astype(self.dtype)
+        elif init == "mamba_dt":
+            # Mamba's dt bias: softplus(bias) spans [1e-3, 1e-1], here
+            # log-spaced over the channels (the paper draws it log-uniform)
+            dt = jnp.exp(jnp.linspace(math.log(1e-3), math.log(1e-1),
+                                      shape[-1], dtype=jnp.float32))
+            p = jnp.broadcast_to(dt + jnp.log(-jnp.expm1(-dt)),
+                                 shape).astype(self.dtype)
         else:
             if scale is None:
                 fan_in = shape[0] if len(shape) >= 2 else max(shape[-1], 1)
@@ -183,6 +196,37 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return out.reshape(B, S, Hq, hd)
 
 
+# queries per block of the prefill's attention
+Q_BLOCK = 512
+
+
+def attention_blocked(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                      causal: bool = True, window: Optional[int] = None,
+                      block: int = Q_BLOCK) -> jax.Array:
+    """``attention_ref`` over queries and keys at positions 0..S-1, one
+    block of ``block`` queries at a time (a ``lax.map``): the scores held
+    at once are [B,Hkv,g,block,T], not [B,Hkv,g,S,T]. Each query's row is
+    computed as ``attention_ref`` computes it."""
+    S, T = q.shape[1], k.shape[1]
+    if S <= block:
+        return attention_ref(q, k, v, causal=causal, window=window)
+    nb = -(-S // block)
+    qp = jnp.pad(q, ((0, 0), (0, nb * block - S), (0, 0), (0, 0)))
+    kv_positions = jnp.arange(T)
+
+    def one(i):
+        lo = i * block
+        qb = jax.lax.dynamic_slice_in_dim(qp, lo, block, axis=1)
+        return attention_ref(qb, k, v, causal=causal, window=window,
+                             q_positions=lo + jnp.arange(block),
+                             kv_positions=kv_positions)
+
+    out = jax.lax.map(one, jnp.arange(nb))              # [nb,B,block,H,hd]
+    out = jnp.moveaxis(out, 0, 1).reshape(q.shape[0], nb * block,
+                                          *q.shape[2:])
+    return out[:, :S]
+
+
 # ---------------------------------------------------------------------------
 # GQA attention block
 # ---------------------------------------------------------------------------
@@ -250,10 +294,10 @@ def attn_apply(p: Params, spec: AttnSpec, x: jax.Array, *,
 
 def attn_prefill(p: Params, spec: AttnSpec, x: jax.Array, *,
                  positions: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Like attn_apply but also returns the KV cache."""
+    """Like attn_apply but also returns the KV cache. The prompt sits at
+    positions 0..S-1; its attention runs over blocks of queries."""
     q, k, v = attn_qkv(p, spec, x, positions)
-    out = attention_ref(q, k, v, causal=spec.causal, window=spec.window,
-                        q_positions=positions, kv_positions=positions)
+    out = attention_blocked(q, k, v, causal=spec.causal, window=spec.window)
     return x + _out_proj(out, p["wo"]), {"k": k, "v": v}
 
 
@@ -290,13 +334,19 @@ def _stored_layout(x: jax.Array) -> jax.Array:
     transposes the cache to suit the attention's dots: a relayout of the
     whole cache on the way into the layer loop and again on the way out,
     every token. A narrower head_dim is stored with positions minor, and
-    pinning row-major would itself relayout it. Under an SPMD context
-    the partitioner replicates a value that passes through a layout
+    pinning row-major would itself relayout it. A single KV head is
+    placed major to the positions, as the runtime stores such a cache
+    (row-major would tile the 1-wide heads axis with head_dim and
+    relayout the whole cache in and out). Under an SPMD context the
+    partitioner replicates a value that passes through a layout
     constraint (a whole-cache all-gather), so there XLA keeps the
     choice."""
     if sharding_active() or x.shape[-1] % 128:
         return x
-    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+    order = tuple(range(x.ndim))
+    if x.shape[-2] == 1:
+        order = order[:-3] + (x.ndim - 2, x.ndim - 3, x.ndim - 1)
+    return with_layout_constraint(x, Layout(order))
 
 
 def attn_decode(p: Params, spec: AttnSpec, x: jax.Array,

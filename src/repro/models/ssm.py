@@ -1,10 +1,14 @@
 """Mamba-1 selective SSM block (jamba's recurrent layer).
 
-TPU adaptation: the selective scan is *chunked* — ``lax.scan`` over chunks of
-``CHUNK`` steps with an in-chunk ``associative_scan``. This bounds live
-buffers to [B, CHUNK, d_inner, N] (VMEM/HBM friendly) while keeping the
-parallel form's O(log CHUNK) depth; the sequential carry between chunks is a
-single [B, d_inner, N] state. Decode is a 1-step recurrence on that state.
+TPU adaptation: the selective scan runs position by position, a ``lax.scan``
+unrolled ``UNROLL`` times whose step builds that position's decay and input
+term ([B, N, d_inner], from its dt, x, B and C) and updates the state, so
+live buffers stay at the state's size whatever the sequence length. On one
+TPU v5e this is ten times faster than a chunked parallel scan: one
+Jamba2-3B layer's 8,192-token prefill took 14.6 ms so, and 151 ms as 32
+chunks of an ``associative_scan`` over 256 positions. Decode is the same
+step on the cached state. The state keeps d_inner minor: with the
+16-wide d_state minor the TPU would pad every state buffer to 128 lanes.
 """
 from __future__ import annotations
 
@@ -19,7 +23,7 @@ from repro.configs.base import SSMConfig
 from repro.models.layers import ParamBuilder, rmsnorm
 
 Params = Any
-CHUNK = 256
+UNROLL = 8
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,7 +38,7 @@ class MambaSpec:
 
     @property
     def dt_rank(self) -> int:
-        return max(1, math.ceil(self.d_model / 16))
+        return self.cfg.rank(self.d_model)
 
 
 def mamba_init(b: ParamBuilder, spec: MambaSpec) -> None:
@@ -46,11 +50,15 @@ def mamba_init(b: ParamBuilder, spec: MambaSpec) -> None:
     b.add("conv_b", (di,), ("ssm_inner_nt",), init="zeros")
     b.add("x_proj", (di, R + 2 * N), ("ssm_inner", None))
     b.add("dt_proj", (R, di), (None, "ssm_inner"), scale=1.0 / math.sqrt(R))
-    b.add("dt_bias", (di,), ("ssm_inner_nt",), init="zeros")
-    b.add("A_log", (di, N), ("ssm_inner_nt", None), init="zeros")
+    b.add("dt_bias", (di,), ("ssm_inner_nt",), init="mamba_dt")
+    b.add("A_log", (di, N), ("ssm_inner_nt", None), init="s4d_real")
     b.add("D", (di,), ("ssm_inner_nt",), init="ones")
     b.add("out_proj", (di, d), ("ssm_inner", "embed"),
           scale=1.0 / math.sqrt(di))
+    if spec.cfg.inner_norms:
+        b.add("dt_norm", (R,), (None,), init="ones")
+        b.add("b_norm", (N,), (None,), init="ones")
+        b.add("c_norm", (N,), (None,), init="ones")
 
 
 def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
@@ -67,23 +75,48 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return y, xp[:, -(W - 1):]
 
 
-def _ssm_inputs(p: Params, spec: MambaSpec, x: jax.Array):
-    """x: [B,S,di] (post-conv, post-silu) -> (dA [B,S,di,N], bx, C)."""
+def _ssm_params(p: Params, spec: MambaSpec, x: jax.Array):
+    """x: [B,S,di] (post-conv, post-silu) -> per-step (dt [B,S,di] f32,
+    B [B,S,N] f32, C [B,S,N] f32), with Jamba's norms on dt, B and C."""
     N, R = spec.cfg.d_state, spec.dt_rank
     xdb = x @ p["x_proj"]                                     # [B,S,R+2N]
     dt_r, Bm, Cm = jnp.split(xdb, [R, R + N], axis=-1)
-    dt = jax.nn.softplus((dt_r @ p["dt_proj"]) + p["dt_bias"])  # [B,S,di]
-    dt = dt.astype(jnp.float32)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))              # [di,N]
-    dA = dt[..., None] * A                                    # [B,S,di,N]
-    bx = (dt * x.astype(jnp.float32))[..., None] * Bm.astype(jnp.float32)[:, :, None, :]
-    return dA, bx, Cm.astype(jnp.float32)
+    if spec.cfg.inner_norms:
+        dt_r = rmsnorm(dt_r, p["dt_norm"], spec.norm_eps)
+        Bm = rmsnorm(Bm, p["b_norm"], spec.norm_eps)
+        Cm = rmsnorm(Cm, p["c_norm"], spec.norm_eps)
+    f32 = jnp.float32
+    dt = jax.nn.softplus((dt_r @ p["dt_proj"]).astype(f32)
+                         + p["dt_bias"].astype(f32))          # [B,S,di]
+    return dt, Bm.astype(f32), Cm.astype(f32)
 
 
-def _scan_combine(left, right):
-    a1, b1 = left
-    a2, b2 = right
-    return a1 * a2, a2 * b1 + b2
+def _neg_a(p: Params) -> jax.Array:
+    """-exp(A_log), transposed to [N, di]."""
+    return -jnp.exp(p["A_log"].astype(jnp.float32)).T
+
+
+def _scan_terms(A: jax.Array, dt: jax.Array, x: jax.Array, Bm: jax.Array):
+    """Decays exp(dt A) and inputs dt x B of the recurrence
+    h_t = decay_t * h_{t-1} + input_t, each [..., N, di] (f32)."""
+    decay = jnp.exp(dt[..., None, :] * A)
+    inp = (dt * x.astype(jnp.float32))[..., None, :] * Bm[..., :, None]
+    return decay, inp
+
+
+def _read_out(h: jax.Array, C: jax.Array) -> jax.Array:
+    """y = sum_n h[..., n, :] C[..., n] in f32 on the vector unit (a dot
+    would round the state to bf16 on the TPU's matrix unit)."""
+    return jnp.sum(h * C[..., None], axis=-2)
+
+
+def _step(A: jax.Array, h: jax.Array, dt: jax.Array, x: jax.Array,
+          Bm: jax.Array, C: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One position of the recurrence h = exp(dt A) h + dt x B, read out
+    by C. h: [B,N,di]; dt, x: [B,di]; Bm, C: [B,N]. Returns (h, y [B,di])."""
+    decay, u = _scan_terms(A, dt, x, Bm)
+    h = decay * h + u
+    return h, _read_out(h, C)
 
 
 def _mamba_forward(p: Params, spec: MambaSpec, x: jax.Array,
@@ -95,27 +128,16 @@ def _mamba_forward(p: Params, spec: MambaSpec, x: jax.Array,
     xin, z = jnp.split(h0 @ p["in_proj"], 2, axis=-1)
     xc, conv_state = _causal_conv(xin, p["conv_w"], p["conv_b"])
     xc = jax.nn.silu(xc)
-    dA, bx, Cm = _ssm_inputs(p, spec, xc)
+    dt, Bm, Cm = _ssm_params(p, spec, xc)
+    A = _neg_a(p)                                             # [N,di]
 
-    nc = max(1, S // CHUNK)
-    Q = S // nc
-    assert nc * Q == S, f"seq {S} not divisible into chunks of {Q}"
-
-    def chunk_body(h_carry, inp):
-        dA_c, bx_c, C_c = inp                                 # [B,Q,di,N],[B,Q,N]
-        decay = jnp.exp(dA_c)
-        a_cum, b_cum = jax.lax.associative_scan(
-            _scan_combine, (decay, bx_c), axis=1)
-        h_all = a_cum * h_carry[:, None] + b_cum              # [B,Q,di,N]
-        y = jnp.einsum("bqdn,bqn->bqd", h_all, C_c)
-        return h_all[:, -1], y
-
-    reshape = lambda t: jnp.moveaxis(
-        t.reshape(B, nc, Q, *t.shape[2:]), 1, 0)              # [nc,B,Q,...]
-    h_init = jnp.zeros((B, di, N), jnp.float32)
-    h_last, ys = jax.lax.scan(chunk_body, h_init,
-                              (reshape(dA), reshape(bx), reshape(Cm)))
-    y = jnp.moveaxis(ys, 0, 1).reshape(B, S, di)              # [B,S,di]
+    by_position = lambda t: jnp.moveaxis(t, 1, 0)             # [S,B,...]
+    h_last, ys = jax.lax.scan(
+        lambda h, inp: _step(A, h, *inp),
+        jnp.zeros((B, N, di), jnp.float32),
+        (by_position(dt), by_position(xc), by_position(Bm), by_position(Cm)),
+        unroll=UNROLL)
+    y = jnp.moveaxis(ys, 0, 1)                                # [B,S,di]
     y = (y + p["D"].astype(jnp.float32) * xc.astype(jnp.float32)).astype(x.dtype)
     out = (y * jax.nn.silu(z)) @ p["out_proj"]
     return x + out, {"h": h_last, "conv": conv_state}
@@ -134,7 +156,7 @@ def mamba_prefill(p: Params, spec: MambaSpec, x: jax.Array,
 def mamba_cache_init(spec: MambaSpec, batch: int, dtype) -> Dict[str, Any]:
     di, N, W = spec.d_inner, spec.cfg.d_state, spec.cfg.d_conv
     return {
-        "h": jnp.zeros((batch, di, N), jnp.float32),
+        "h": jnp.zeros((batch, N, di), jnp.float32),
         "conv": jnp.zeros((batch, W - 1, di), dtype),
     }
 
@@ -142,14 +164,14 @@ def mamba_cache_init(spec: MambaSpec, batch: int, dtype) -> Dict[str, Any]:
 def mamba_decode(p: Params, spec: MambaSpec, x: jax.Array,
                  cache: Dict[str, Any]) -> Tuple[jax.Array, Dict[str, Any]]:
     """One-token decode. x: [B,1,d]."""
-    B = x.shape[0]
     h0 = rmsnorm(x, p["norm"], spec.norm_eps)
     xin, z = jnp.split(h0 @ p["in_proj"], 2, axis=-1)
     xc, conv_state = _causal_conv(xin, p["conv_w"], p["conv_b"], cache["conv"])
     xc = jax.nn.silu(xc)
-    dA, bx, Cm = _ssm_inputs(p, spec, xc)                     # S=1
-    h_new = jnp.exp(dA[:, 0]) * cache["h"] + bx[:, 0]         # [B,di,N]
-    y = jnp.einsum("bdn,bn->bd", h_new, Cm[:, 0])[:, None]
+    dt, Bm, Cm = _ssm_params(p, spec, xc)                     # S=1
+    h_new, y = _step(_neg_a(p), cache["h"], dt[:, 0], xc[:, 0], Bm[:, 0],
+                     Cm[:, 0])
+    y = y[:, None]
     y = (y + p["D"].astype(jnp.float32) * xc.astype(jnp.float32)).astype(x.dtype)
     out = (y * jax.nn.silu(z)) @ p["out_proj"]
     return x + out, {"h": h_new, "conv": conv_state}

@@ -2,10 +2,11 @@
 
 An architecture is compiled (at trace time) into a *group program*: an
 ordered list of ``Block`` templates covering one period of the arch's layer
-pattern (e.g. jamba: ``[attn+mlp, mamba+moe, mamba+mlp, ...]`` — 8 layers;
-gemma3: 5 sliding-window + 1 global). The full stack is a ``jax.lax.scan``
-over ``n_groups`` stacked copies of the group params, so compile time is
-independent of depth (96-layer nemotron lowers as fast as 12-layer xlstm).
+pattern (e.g. jamba: ``[mamba+mlp, mamba+moe, ..., attn+mlp, ...]`` — 8
+layers, attention at offset 4; gemma3: 5 sliding-window + 1 global). The
+full stack is a ``jax.lax.scan`` over ``n_groups`` stacked copies of the
+group params, so compile time is independent of depth (96-layer nemotron
+lowers as fast as 12-layer xlstm).
 """
 from __future__ import annotations
 
@@ -66,7 +67,7 @@ def build_group(cfg: ArchConfig) -> Tuple[List[Block], int]:
     blocks = []
     for j in range(gs):
         # --- token mixer ------------------------------------------------
-        if cfg.attn_every > 1 and (j % cfg.attn_every) != 0:
+        if not cfg.is_attn_layer(j):
             blocks.append(Block("mamba", f"l{j}_mamba",
                                 S.MambaSpec(cfg.d_model, cfg.ssm, cfg.norm_eps)))
         else:
@@ -77,7 +78,8 @@ def build_group(cfg: ArchConfig) -> Tuple[List[Block], int]:
                     window = cfg.local_window
             blocks.append(Block("attn", f"l{j}_attn", L.AttnSpec(
                 cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
-                cfg.rope_theta, cfg.norm_eps, window=window)))
+                cfg.rope_theta, cfg.norm_eps, window=window,
+                use_rope=cfg.use_rope)))
             if cfg.encoder is not None:
                 blocks.append(Block("cross_attn", f"l{j}_xattn", L.AttnSpec(
                     cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
@@ -368,7 +370,7 @@ def cache_dims(blocks: List[Block]) -> Any:
             d = ("layers", "batch", "kvseq", "kv_heads", "head_dim")
             out[blk.name] = {"mk": d, "mv": d}
         elif blk.kind == "mamba":
-            out[blk.name] = {"h": ("layers", "batch", "ssm_inner", None),
+            out[blk.name] = {"h": ("layers", "batch", None, "ssm_inner"),
                              "conv": ("layers", "batch", None, "ssm_inner")}
         elif blk.kind == "mlstm":
             out[blk.name] = {"C": ("layers", "batch", None, "head_dim", None),
@@ -377,4 +379,18 @@ def cache_dims(blocks: List[Block]) -> Any:
         elif blk.kind == "slstm":
             d = ("layers", "batch", "embed_nt")
             out[blk.name] = {k: d for k in ("c", "n", "h", "m")}
+    return out
+
+
+def cache_bytes(blocks: List[Block], cache: Params) -> Dict[str, int]:
+    """Bytes of a decode cache by what it holds: ``attn`` (K/V rows, which
+    grow with the positions filled) and ``ssm`` (recurrent state of a
+    fixed size per sequence)."""
+    out = {"attn": 0, "ssm": 0}
+    for blk in blocks:
+        if blk.name in cache:
+            kind = "attn" if blk.kind in ("attn", "cross_attn") else "ssm"
+            out[kind] += sum(
+                int(t.size) * t.dtype.itemsize
+                for t in jax.tree.leaves(cache[blk.name]))
     return out

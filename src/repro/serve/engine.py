@@ -1,9 +1,13 @@
-"""Serving engine: batched prefill + decode with donated caches.
+"""Serving engine: prefill + decode with donated caches.
 
 Also hosts ``ServeApp`` — a CACS-managed inference job whose checkpoint
 state is {params, KV/SSM caches, generated tokens}: suspending a *serving*
 job mid-generation and resuming it elsewhere (even on another "cloud") is
-the paper's job-swapping use case applied to inference.
+the paper's job-swapping use case applied to inference. A hybrid model's
+cache holds both kinds of state side by side: attention K/V rows (bf16,
+growing with the positions filled) and recurrent state (f32 ``h`` and the
+conv tail, fixed per sequence). The decode, the pin and the restore carry
+every leaf unchanged, whichever kind.
 """
 from __future__ import annotations
 
@@ -17,6 +21,7 @@ import numpy as np
 from repro.ckpt.snapshot import DeferredSnapshot, SnapshotHandle
 from repro.configs.base import ArchConfig
 from repro.models.model import Model, build_model
+from repro.models.transformer import cache_bytes
 from repro.obs.telemetry import SampleView, registry, unique_name
 from repro.obs.trace import tracer
 from repro.sim.simtime import active_clock
@@ -28,11 +33,39 @@ class Engine:
         self.params = params
         self.cache_len = cache_len
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
-        self._prefill = jax.jit(
-            lambda p, b: model.prefill(p, b, cache_len=cache_len))
+        self._prefill_row = jax.jit(self._into_row, donate_argnums=(1,))
 
-    def prefill(self, batch: Dict[str, jax.Array]):
-        return self._prefill(self.params, batch)
+    def _into_row(self, params, cache, batch, row):
+        logits, c = self.model.prefill(params, batch,
+                                       cache_len=self.cache_len)
+        return logits, jax.tree.map(
+            lambda t, u: jax.lax.dynamic_update_slice_in_dim(
+                t, u.astype(t.dtype), row, axis=1), cache, c)
+
+    def prefill(self, batch: Dict[str, Any]):
+        """Prefill the batch one row (slot) at a time into a batch cache
+        from ``init_cache``: each row's K/V and recurrent state are written
+        at its own row (every cache leaf has the batch on axis 1), so no
+        activation ever spans the whole batch. One ``serve/prefill`` span
+        holds a ``serve/prefill_row`` span per row. Returns (the first
+        tokens [B,1], the cache)."""
+        tr = tracer()
+        tokens = batch["tokens"]
+        rows = tokens.shape[0]
+        cache = self.model.init_cache(rows, self.cache_len)
+        firsts = []
+        with tr.span("serve/prefill", cat="serve",
+                     args={"rows": rows, "tokens": int(tokens.size)}):
+            for r in range(rows):
+                with tr.span("serve/prefill_row", cat="serve",
+                             args={"row": r, "tokens": tokens.shape[1]}):
+                    one = {k: jnp.asarray(v[r:r + 1])
+                           for k, v in batch.items()}
+                    logits, cache = self._prefill_row(
+                        self.params, cache, one, jnp.int32(r))
+                    firsts.append(jax.block_until_ready(
+                        jnp.argmax(logits, -1).astype(jnp.int32)))
+        return jnp.concatenate(firsts)[:, None], cache
 
     def decode(self, cache, token, pos):
         return self._decode(self.params, cache, token, pos)
@@ -45,10 +78,8 @@ class Engine:
         if self.model.cfg.frontend is not None \
                 and self.model.cfg.family != "encdec":
             prompt_len += self.model.cfg.frontend_len
-        logits, cache = self.prefill(batch)
-        out = []
-        token = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        out.append(token)
+        token, cache = self.prefill(batch)
+        out = [token]
         for i in range(1, n_tokens):
             pos = jnp.int32(prompt_len + i - 1)
             logits, cache = self.decode(cache, token, pos)
@@ -90,6 +121,7 @@ class ServeApp:
         self._stall_hist = registry().histogram(
             unique_name("serve.ckpt_stall_s"))
         self.restarts = 0
+        self._cache_nbytes: Dict[str, int] = {}
 
     def _build(self):
         if self.params is None:
@@ -98,8 +130,8 @@ class ServeApp:
                              cache_len=self.cache_len)
 
     def start(self, ctx, restore_state: Optional[Any]) -> None:
-        self._build()
         if restore_state is not None:
+            # the image's weights: no fresh init beside them on the device
             with self._lock:
                 self.params = restore_state["params"]
                 self.cache = restore_state["cache"]
@@ -107,13 +139,23 @@ class ServeApp:
                 self._last_token = jnp.asarray(restore_state["last_token"])
                 self.tokens_out = [np.asarray(restore_state["tokens_out"])] \
                     if self.generated else []
-            self.engine = Engine(self.model, self.params,
-                                 cache_len=self.cache_len)
+            self._publish_cache_bytes()
             self.restarts += 1
+        self._build()
         self._stop.clear()
         self._failure = None
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
+
+    def _publish_cache_bytes(self) -> None:
+        self._cache_nbytes = cache_bytes(self.model.blocks, self.cache)
+        for kind, n in self._cache_nbytes.items():
+            registry().set_gauge(f"serve.cache_bytes.{kind}", n)
+
+    def cache_bytes(self) -> Dict[str, int]:
+        """Bytes of the decode cache by kind (``attn`` K/V rows, ``ssm``
+        recurrent state): what a pin copies on the device."""
+        return dict(self._cache_nbytes)
 
     def _run(self) -> None:
         if self.cache is None:
@@ -121,14 +163,14 @@ class ServeApp:
             prompt = rng.integers(
                 0, self.cfg.vocab_size, (self.batch, self.prompt_len)
             ).astype(np.int32)
-            logits, cache = self.engine.prefill({"tokens": jnp.asarray(prompt)})
-            token = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+            token, cache = self.engine.prefill({"tokens": prompt})
             with self._cond:
                 self.cache = cache
                 self._last_token = token
                 self.tokens_out.append(np.asarray(token))
                 self.generated = 1
                 self._cond.notify_all()
+            self._publish_cache_bytes()
         clock = active_clock()
         while not self._stop.is_set() and self.generated < self.n_tokens:
             if self.token_delay_s:

@@ -169,3 +169,19 @@ def test_sharded_decode_updates_the_donated_cache_in_place(
                 jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
                 ).compile()
     _assert_cache_in_place(compiled, cache, cfg.n_layers)
+
+
+def test_hybrid_decode_updates_both_kinds_of_state_in_place(
+        one_chip, no_persistent_cache):
+    """A Jamba period (13 Mamba layers, one attention layer with a single
+    KV head) at 8 rows x 16,384 positions: the decode writes its K/V row
+    and every Mamba layer's state into the donated buffers and holds no
+    copy of the KV cache or of the recurrent state. A row-major pin of
+    the 1-wide heads axis relaid the whole K and V in and out (76.6 MB
+    of scratch)."""
+    base = get_config("jamba2-3b")
+    cfg = dataclasses.replace(
+        base, n_layers=14, d_model=512, n_heads=4, d_ff=1024,
+        vocab_size=1024, ssm=dataclasses.replace(base.ssm, dt_rank=None))
+    compiled, cache = _decode_on_chip(build_model(cfg), one_chip, 8, 16384)
+    _assert_cache_in_place(compiled, cache, cfg.n_layers)

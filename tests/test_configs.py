@@ -15,11 +15,12 @@ EXPECTED = {
     "llama4-maverick-400b-a17b": (400e9, 0.25),
     "llama4-scout-17b-a16e": (109e9, 0.30),
     "jamba-v0.1-52b": (52e9, 0.30),
+    "jamba2-3b": (3.03e9, 0.02),         # 3,029,337,472 weights held
 }
 
 
 def test_registry_has_all_assigned():
-    assert len(ASSIGNED_ARCHS) == 10
+    assert len(ASSIGNED_ARCHS) == 11
     for a in ASSIGNED_ARCHS:
         assert get_config(a).name == a
 
@@ -56,7 +57,7 @@ def test_shape_grid():
             assert shape_applicable(cfg, SHAPES[s])[0]
     live = sum(1 for a in ASSIGNED_ARCHS for s in SHAPES.values()
                if shape_applicable(get_config(a), s)[0])
-    assert live == 33   # 30 universal + 3 subquadratic long_500k
+    assert live == 37   # 33 universal + 4 subquadratic long_500k
 
 
 @pytest.mark.parametrize("arch", sorted(ASSIGNED_ARCHS))
