@@ -32,8 +32,13 @@ class Engine:
         self.model = model
         self.params = params
         self.cache_len = cache_len
-        self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
+        self._decode = jax.jit(self._greedy_step, donate_argnums=(1,))
         self._prefill_row = jax.jit(self._into_row, donate_argnums=(1,))
+
+    def _greedy_step(self, params, cache, token, pos):
+        logits, cache = self.model.decode_step(params, cache, token, pos)
+        return (jnp.argmax(logits, -1)[:, None].astype(jnp.int32), cache,
+                pos + 1)
 
     def _into_row(self, params, cache, batch, row):
         logits, c = self.model.prefill(params, batch,
@@ -68,6 +73,9 @@ class Engine:
         return jnp.concatenate(firsts)[:, None], cache
 
     def decode(self, cache, token, pos):
+        """One greedy decode step in one dispatch, the cache donated:
+        (the next tokens [B,1] int32, the cache, ``pos + 1``), all left
+        on the device."""
         return self._decode(self.params, cache, token, pos)
 
     def generate(self, batch: Dict[str, jax.Array], n_tokens: int,
@@ -79,11 +87,10 @@ class Engine:
                 and self.model.cfg.family != "encdec":
             prompt_len += self.model.cfg.frontend_len
         token, cache = self.prefill(batch)
+        pos = jnp.int32(prompt_len)
         out = [token]
-        for i in range(1, n_tokens):
-            pos = jnp.int32(prompt_len + i - 1)
-            logits, cache = self.decode(cache, token, pos)
-            token = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
+        for _ in range(1, n_tokens):
+            token, cache, pos = self.decode(cache, token, pos)
             out.append(token)
         return jnp.concatenate(out, axis=1)
 
@@ -105,9 +112,14 @@ class ServeApp:
         self.token_delay_s = token_delay_s   # rate-limit (tests/demos)
         self.params: Any = None
         self.cache: Any = None
+        # tokens in host memory; ``generated`` counts them and nothing else
         self.tokens_out: List[np.ndarray] = []
         self.generated = 0
+        # the slot: the cache and the last token of the newest dispatched
+        # step; ``_pending`` holds the tokens of dispatched steps not yet
+        # fetched to the host, oldest first (at most two, for a moment)
         self._last_token = None
+        self._pending: List[jax.Array] = []
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._lock = threading.Lock()
@@ -139,6 +151,7 @@ class ServeApp:
                 self._last_token = jnp.asarray(restore_state["last_token"])
                 self.tokens_out = [np.asarray(restore_state["tokens_out"])] \
                     if self.generated else []
+                self._pending = []
             self._publish_cache_bytes()
             self.restarts += 1
         self._build()
@@ -157,6 +170,11 @@ class ServeApp:
         recurrent state): what a pin copies on the device."""
         return dict(self._cache_nbytes)
 
+    def _slot_count(self) -> int:
+        """Tokens of the step the slot holds: delivered, plus dispatched
+        and not yet fetched."""
+        return self.generated + len(self._pending)
+
     def _run(self) -> None:
         if self.cache is None:
             rng = np.random.Generator(np.random.PCG64(self.seed))
@@ -172,13 +190,19 @@ class ServeApp:
                 self._cond.notify_all()
             self._publish_cache_bytes()
         clock = active_clock()
-        while not self._stop.is_set() and self.generated < self.n_tokens:
+        # the position stays on the device: each step returns the next one
+        pos = jnp.int32(self.prompt_len + self.generated - 1)
+        # One step in flight: step n+1 is dispatched with token n still on
+        # the device, and token n is fetched while step n+1 runs, so the
+        # device never waits for the host's round trip.
+        while not self._stop.is_set() and self._slot_count() < self.n_tokens:
             if self.token_delay_s:
                 clock.sleep(self.token_delay_s)
             tr = tracer()
+            step = self._slot_count()
+            overlapped = bool(self._pending)
             with tr.span("serve/step", cat="serve",
-                         args={"generated": self.generated}):
-                pos = jnp.int32(self.prompt_len + self.generated - 1)
+                         args={"generated": step, "overlapped": overlapped}):
                 # NOTE: cache is donated; keep the swap atomic wrt
                 # checkpointing
                 with self._lock:
@@ -186,8 +210,8 @@ class ServeApp:
                     self.cache = None
                 with tr.span("serve/dispatch", cat="serve"):
                     try:
-                        logits, new_cache = self.engine.decode(cache, token,
-                                                               pos)
+                        token, new_cache, pos = self.engine.decode(
+                            cache, token, pos)
                     except BaseException as e:     # noqa: BLE001
                         # Restore the surrendered slot: leaving it None
                         # would make every _capture (snapshot_async,
@@ -197,26 +221,48 @@ class ServeApp:
                         # to consume the donated buffer, a later restore
                         # re-reads the newest committed image instead), so
                         # a suspend issued after the fault still swaps out
-                        # cleanly.
+                        # cleanly. The tokens still in flight are
+                        # delivered.
                         with self._cond:
                             self.cache = cache
                             self._failure = e
                             self._cond.notify_all()
                         registry().inc("serve.decode_failures",
                                        note=f"{type(e).__name__}: {e}")
+                        self._deliver(0)
                         return
-                    token = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+                if overlapped:
+                    registry().inc("serve.decode_overlapped")
                 with self._cond:
-                    with tr.span("serve/sync", cat="serve"):
-                        self.cache = jax.block_until_ready(new_cache)
-                        self._last_token = token
-                        self.tokens_out.append(np.asarray(token))
-                    self.generated += 1
+                    self.cache = new_cache
+                    self._last_token = token
+                    self._pending.append(token)
                     self._cond.notify_all()
+                last = self._stop.is_set() or step + 1 >= self.n_tokens
+                with tr.span("serve/sync", cat="serve"):
+                    self._deliver(0 if last else 1)
+        self._deliver(0)
+
+    def _deliver(self, keep: int) -> None:
+        """Fetch the oldest dispatched tokens to the host until ``keep``
+        remain in flight; ``generated`` counts each one as it lands. Only
+        the decode thread changes ``_pending``, so the fetch runs outside
+        the lock: a pin never waits for the device."""
+        while len(self._pending) > keep:
+            host = np.asarray(self._pending[0])
+            with self._cond:
+                self._pending.pop(0)
+                self.tokens_out.append(host)
+                self.generated += 1
+                self._cond.notify_all()
 
     def _capture(self) -> Dict[str, Any]:
         """Pin a consistent snapshot under the lock (waits out the window
-        where the donated cache is surrendered to an in-flight decode).
+        where the donated cache is surrendered to a decode's dispatch).
+        Cache, last token, tokens and count are the newest dispatched
+        step's: tokens not yet fetched go in as device arrays, which
+        ``_materialize`` fetches, so the image's ``generated`` may be one
+        ahead of the public counter.
         Params/tokens are references (never donated, immutable); the KV
         cache is **copied on device** — the very next decode step donates
         the live buffer, so a pinned reference would read as "Array has
@@ -241,9 +287,9 @@ class ServeApp:
                 "cache": jax.tree_util.tree_map(
                     lambda x: jnp.array(x, copy=True)
                     if isinstance(x, jax.Array) else x, self.cache),
-                "generated": self.generated,
+                "generated": self._slot_count(),
                 "last_token": self._last_token,
-                "tokens_out": list(self.tokens_out),
+                "tokens_out": self.tokens_out + self._pending,
             }
 
     @staticmethod
@@ -260,9 +306,9 @@ class ServeApp:
     def snapshot_async(self, *, step: Optional[int] = None,
                        codec: Optional[str] = None) -> SnapshotHandle:
         """Staged snapshot: capture pins params/cache/token references
-        (token-latency stall only while a decode holds the donated
-        cache); the concat + any host copies run at ``resolve()`` on the
-        writer thread. The KV cache stays lossless regardless of
+        (token-latency stall only while a decode's dispatch holds the
+        donated cache); the concat + any host copies run at ``resolve()``
+        on the writer thread. The KV cache stays lossless regardless of
         ``codec`` — quantizing it would perturb the generated stream,
         and suspend/resume guarantees the tokens are unchanged."""
         clock = active_clock()

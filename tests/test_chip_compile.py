@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.qsnap import QSNAP_BLOCK, qsnap_dequantize, qsnap_quantize
 from repro.models import build_model
+from repro.serve.engine import Engine
 
 # repro-100m's embedding leaf: vocab 32768 x d_model 768
 EMBED_ELEMS = 32768 * 768
@@ -101,7 +102,7 @@ def _decode_on_chip(model, sharding, batch: int, cache_len: int):
             s.shape, s.dtype, sharding=sharding), tree)
 
     cache = on_chip(model.abstract_cache(batch, cache_len))
-    compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+    compiled = Engine(model, None, cache_len=cache_len)._decode.lower(
         on_chip(model.abstract_params()), cache,
         jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=sharding),
         jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)).compile()
@@ -112,7 +113,8 @@ def _decode_on_chip(model, sharding, batch: int, cache_len: int):
 def test_decode_updates_the_donated_cache_in_place(one_chip,
                                                    no_persistent_cache,
                                                    head_dim):
-    """The decode step as ``Engine`` jits it (cache donated) writes the
+    """The decode step as ``Engine`` jits it (cache donated, the greedy
+    token and the next position computed in the same program) writes the
     token's row into the cache buffers it was given and holds no copy of
     the cache: its scratch stays under one layer's K+V. A 64-wide
     head_dim is stored with positions minor, a 128-wide one row-major;

@@ -229,14 +229,28 @@ def test_suspend_mid_decode_cross_cloud_stream_bit_identical():
     made = []
 
     class _Gated(ServeApp):
+        """Parks the dispatch of the step that would take the slot past 5
+        tokens until released; once the swap-out has pinned, holds the
+        next dispatch until the suspend stops the app, so the suspend
+        lands mid-decode however long its write takes. The resumed
+        incarnation (``restarts`` 1) decodes freely."""
+        pinned = False
+
+        def _capture(self):
+            snap = super()._capture()
+            self.pinned = True
+            return snap
+
         def _build(self):
             super()._build()
             real = self.engine.decode
 
             def decode(cache, token, pos):
-                if self.generated >= 5 and not gate_release.is_set():
+                if self._slot_count() >= 5 and not gate_release.is_set():
                     gate_entered.set()
                     gate_release.wait(30)
+                if self.pinned and not self.restarts:
+                    self._stop.wait(30)
                 return real(cache, token, pos)
             self.engine.decode = decode
 
