@@ -4,8 +4,8 @@ Codecs operate on raw little-endian chunk bytes:
   * ``raw``       — identity.
   * ``zlib``      — lossless deflate (cheap CPU, good on low-entropy state).
   * ``int8``      — blockwise absmax int8 quantization of float leaves
-                    (lossy; used for *swap-out* images of preempted jobs and
-                    for gradient compression — not for exact restarts).
+                    (lossy; used for *swap-out* images of preempted jobs —
+                    not for exact restarts).
   * ``int8+zlib`` — both.
 
 The int8 codec's result equals ``repro.kernels.ref.qsnap_ref``'s exactly — the

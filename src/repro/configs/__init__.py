@@ -2,15 +2,14 @@
 
 ``get_config(name)`` resolves an arch id (e.g. ``--arch gemma3-12b``) to its
 ``ArchConfig``.  ``reduced(cfg)`` derives the small same-family config used by
-per-arch CPU smoke tests (full configs are only ever lowered via the dry-run).
+per-arch CPU smoke tests.
 """
 from __future__ import annotations
 
 import dataclasses
 
 from repro.configs.base import (ArchConfig, EncoderConfig, MoEConfig,
-                                ShapeConfig, SHAPE_GRID, SHAPES, SSMConfig,
-                                XLSTMConfig, shape_applicable)
+                                SSMConfig, XLSTMConfig)
 
 from repro.configs.seamless_m4t_medium import CONFIG as SEAMLESS_M4T_MEDIUM
 from repro.configs.internlm2_1_8b import CONFIG as INTERNLM2_1_8B
@@ -84,6 +83,5 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
 
 __all__ = [
     "ArchConfig", "EncoderConfig", "MoEConfig", "SSMConfig", "XLSTMConfig",
-    "ShapeConfig", "SHAPE_GRID", "SHAPES", "shape_applicable",
     "ARCH_REGISTRY", "ASSIGNED_ARCHS", "get_config", "reduced",
 ]

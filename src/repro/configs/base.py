@@ -7,7 +7,7 @@ Configs never touch jax device state at import time.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,7 +89,8 @@ class ArchConfig:
     frontend: Optional[str] = None
     frontend_len: int = 0                    # tokens contributed by frontend
 
-    # Sub-quadratic? (drives long_500k applicability)
+    # Sub-quadratic decode cost per token (recurrent state in place of a
+    # growing KV cache). Descriptive: no code path branches on it.
     subquadratic: bool = False
 
     # FSDP (param/optimizer sharding over the data axis) on by default for
@@ -170,31 +171,3 @@ class ArchConfig:
         inactive = n_moe_layers * (self.moe.num_experts - self.moe.top_k) * expert_p
         return full - inactive
 
-
-# ---------------------------------------------------------------------------
-# Input shape grid (assigned): every LM arch is paired with these four shapes.
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                    # "train" | "prefill" | "decode"
-
-
-SHAPE_GRID: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4_096, 256, "train"),
-    ShapeConfig("prefill_32k", 32_768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32_768, 128, "decode"),
-    ShapeConfig("long_500k", 524_288, 1, "decode"),
-)
-
-SHAPES = {s.name: s for s in SHAPE_GRID}
-
-
-def shape_applicable(cfg: ArchConfig, shape: ShapeConfig) -> Tuple[bool, str]:
-    """Whether (arch, shape) is a live cell per DESIGN.md §4."""
-    if shape.name == "long_500k" and not cfg.subquadratic:
-        return False, "long_500k skipped: pure full-attention arch (quadratic)"
-    return True, ""

@@ -5,7 +5,7 @@ to the kernels' [B,H,S,hd], pad sequence dims to block multiples (padding
 is masked via ``kv_len``), and select an implementation:
 
   impl="pallas"    — real kernel (TPU) or interpret mode (CPU tests)
-  impl="ref"       — the pure-jnp oracle (used by models on CPU/dry-run)
+  impl="ref"       — the pure-jnp oracle (used by models on CPU)
 
 On a CPU-only host ``default_impl()`` returns "ref"; tests force
 impl="pallas", interpret=True to execute the kernel bodies.

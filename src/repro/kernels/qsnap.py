@@ -1,10 +1,9 @@
-"""qsnap — blockwise int8 quantization kernel for checkpoint images and
-gradient compression.
+"""qsnap — blockwise int8 quantization kernel for checkpoint images.
 
 The paper's scaling lever is checkpoint image *size* (Table 2, §5.2). On a
-TPU fleet the equivalent hot path is the device->host copy and the
-DP-gradient all-reduce: quantizing on device (VMEM-resident, one pass)
-cuts both by ~4x for bf16/f32 state. Each 256-element block stores one f32
+TPU fleet the equivalent hot path is the device->host copy: quantizing on
+device (VMEM-resident, one pass) cuts it by ~4x for f32 state and ~2x for
+bf16. Each 256-element block stores one f32
 absmax scale + 256 int8 codes — the exact format ``repro.ckpt.compression``
 writes, so device- and host-compressed images are interchangeable.
 

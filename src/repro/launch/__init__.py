@@ -1,3 +1,3 @@
-# NOTE: repro.launch.dryrun must be imported FIRST in a fresh process (it
-# sets XLA_FLAGS for 512 host devices). This package init intentionally
-# imports nothing.
+"""Launchers for the hosted jobs (``train``, ``serve``), the persistent
+compile cache they share (``cache``) and a device-mesh helper (``mesh``).
+Importing the package touches no jax device state."""

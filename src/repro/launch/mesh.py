@@ -1,34 +1,14 @@
-"""Production mesh construction.
+"""Device-mesh construction.
 
-``make_production_mesh`` is a FUNCTION (module import never touches jax
-device state): single-pod = 16x16 (256 chips, TPU v5e pod), multi-pod =
-2x16x16 (512 chips). The dry-run forces 512 host devices via XLA_FLAGS
-before any jax import (see dryrun.py).
+A FUNCTION, so importing the module never touches jax device state: the
+caller sets ``--xla_force_host_platform_device_count`` before its first
+jax import when it wants several CPU devices.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = 1
-    for s in shape:
-        n *= s
-    devices = jax.devices()
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devices)} — "
-            f"set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
-            f"before importing jax (dryrun.py does this)")
-    import numpy as np
-    dev_array = np.asarray(devices[:n]).reshape(shape)
-    return jax.sharding.Mesh(
-        dev_array, axes,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),

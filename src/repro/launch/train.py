@@ -6,8 +6,8 @@ Two modes:
     the paper's deployment model).
   * raw           — plain loop with an AsyncCheckpointer (for debugging).
 
-On real hardware this process runs once per host; on this CPU container it
-drives a single-device run (the multi-pod path is exercised by dryrun.py).
+On real hardware this process runs once per host; on a CPU host it drives
+a single-device run.
 """
 from __future__ import annotations
 

@@ -4,9 +4,7 @@ The Model is the unit the rest of the system operates on:
   * the trainer builds ``train_step`` from ``model.loss``;
   * the serve engine builds ``prefill`` / ``decode_step``;
   * the CACS checkpoint service snapshots ``{params, opt_state, data_state}``
-    pytrees produced here;
-  * the dry-run lowers ``train_step``/``serve_step`` from
-    ``jax.eval_shape`` results — full-size configs are never materialized.
+    pytrees produced here.
 """
 from __future__ import annotations
 
@@ -73,7 +71,6 @@ cross_entropy.defvjp(_ce_fwd, _ce_bwd)
 @dataclasses.dataclass
 class Model:
     cfg: ArchConfig
-    unroll: bool = False     # python-loop the stack (dry-run cost probes)
 
     def __post_init__(self):
         self.blocks, self.n_groups = T.build_group(self.cfg)
@@ -131,7 +128,7 @@ class Model:
         positions = jnp.arange(frames.shape[1])
         x = constrain(frames.astype(self.dtype), ("dp", "sp", None))
         x, _ = T.stack_forward(enc["stack"], self.enc_blocks, x, positions,
-                               remat=remat, unroll=self.unroll)
+                               remat=remat)
         return L.rmsnorm(x, enc["final_norm"], self.cfg.norm_eps)
 
     def _inputs(self, params: Params, batch: Dict[str, jax.Array],
@@ -161,8 +158,7 @@ class Model:
         x, positions, enc_out = self._inputs(params, batch, remat)
         x = constrain(x, ("dp", "sp", None))
         x, aux = T.stack_forward(params["stack"], self.blocks, x, positions,
-                                 enc_out=enc_out, remat=remat,
-                                 unroll=self.unroll)
+                                 enc_out=enc_out, remat=remat)
         x = L.rmsnorm(x, params["embed"]["final_norm"], cfg.norm_eps)
         logits = L.unembed_apply(params["embed"], x, cfg.tie_embeddings)
         logits = constrain(logits, ("dp", None, "tp"))
@@ -181,8 +177,7 @@ class Model:
         x, positions, enc_out = self._inputs(params, batch, remat=False)
         x = constrain(x, ("dp", "sp", None))
         x, cache = T.stack_prefill(params["stack"], self.blocks, x, positions,
-                                   enc_out=enc_out, cache_len=cache_len,
-                                   unroll=self.unroll)
+                                   enc_out=enc_out, cache_len=cache_len)
         x = L.rmsnorm(x[:, -1:], params["embed"]["final_norm"], cfg.norm_eps)
         logits = L.unembed_apply(params["embed"], x, cfg.tie_embeddings)
         return logits[:, 0], cache
@@ -192,8 +187,7 @@ class Model:
         """token: [B,1] int32; pos: scalar int32. -> (logits [B,V], cache)."""
         cfg = self.cfg
         x = self._embed_tokens(params, token)
-        x, cache = T.stack_decode(params["stack"], self.blocks, x, cache, pos,
-                                  unroll=self.unroll)
+        x, cache = T.stack_decode(params["stack"], self.blocks, x, cache, pos)
         x = L.rmsnorm(x, params["embed"]["final_norm"], cfg.norm_eps)
         logits = L.unembed_apply(params["embed"], x, cfg.tie_embeddings)
         return logits[:, 0], cache
@@ -246,5 +240,5 @@ class Model:
         return out
 
 
-def build_model(cfg: ArchConfig, *, unroll: bool = False) -> Model:
-    return Model(cfg, unroll=unroll)
+def build_model(cfg: ArchConfig) -> Model:
+    return Model(cfg)

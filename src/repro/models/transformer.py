@@ -167,13 +167,9 @@ def stack_dims(blocks: List[Block]) -> Any:
 
 def stack_forward(params_stack: Params, blocks: List[Block], x: jax.Array,
                   positions: jax.Array, *, enc_out: Optional[jax.Array] = None,
-                  remat: bool = True, unroll: bool = False,
+                  remat: bool = True,
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Scan the group program over the stacked params. Returns (x, moe_aux).
-
-    ``unroll=True`` replaces the scan with a Python loop — used by the
-    dry-run's cost probes (XLA cost_analysis counts while bodies once).
-    """
+    """Scan the group program over the stacked params. Returns (x, moe_aux)."""
 
     def body(carry, p_g):
         x, aux = carry
@@ -210,12 +206,6 @@ def stack_forward(params_stack: Params, blocks: List[Block], x: jax.Array,
     else:
         body_fn = body
     carry = (x, jnp.zeros((), jnp.float32))
-    if unroll:
-        n = jax.tree.leaves(params_stack)[0].shape[0]
-        for i in range(n):
-            p_g = jax.tree.map(lambda t, i=i: t[i], params_stack)
-            carry, _ = body_fn(carry, p_g)
-        return carry
     (x, aux), _ = jax.lax.scan(body_fn, carry, params_stack)
     return x, aux
 
@@ -227,7 +217,7 @@ def stack_forward(params_stack: Params, blocks: List[Block], x: jax.Array,
 def stack_prefill(params_stack: Params, blocks: List[Block], x: jax.Array,
                   positions: jax.Array, *,
                   enc_out: Optional[jax.Array] = None,
-                  cache_len: Optional[int] = None, unroll: bool = False,
+                  cache_len: Optional[int] = None,
                   ) -> Tuple[jax.Array, Params]:
     """Forward + per-layer cache construction. cache_len pads KV caches."""
 
@@ -263,15 +253,6 @@ def stack_prefill(params_stack: Params, blocks: List[Block], x: jax.Array,
             x = constrain(x, ("dp", "sp", None))
         return x, caches
 
-    if unroll:
-        n = jax.tree.leaves(params_stack)[0].shape[0]
-        caches = []
-        for i in range(n):
-            p_g = jax.tree.map(lambda t, i=i: t[i], params_stack)
-            x, c = body(x, p_g)
-            caches.append(c)
-        cache_stack = jax.tree.map(lambda *ts: jnp.stack(ts), *caches)
-        return x, cache_stack
     return jax.lax.scan(body, x, params_stack)
 
 
@@ -280,8 +261,8 @@ _RECURRENT_DECODE = {"mamba": S.mamba_decode, "mlstm": X.mlstm_decode,
 
 
 def stack_decode(params_stack: Params, blocks: List[Block], x: jax.Array,
-                 cache_stack: Params, pos: jax.Array, *,
-                 unroll: bool = False) -> Tuple[jax.Array, Params]:
+                 cache_stack: Params, pos: jax.Array,
+                 ) -> Tuple[jax.Array, Params]:
     """One-token decode through the stack. x: [B,1,d].
 
     The stacked cache rides in the carry, not in the scan's xs/ys: each
@@ -318,11 +299,6 @@ def stack_decode(params_stack: Params, blocks: List[Block], x: jax.Array,
 
     carry = (x, cache_stack)
     n = jax.tree.leaves(params_stack)[0].shape[0]
-    if unroll:
-        for i in range(n):
-            p_g = jax.tree.map(lambda t, i=i: t[i], params_stack)
-            carry, _ = body(carry, (i, p_g))
-        return carry
     carry, _ = jax.lax.scan(body, carry, (jnp.arange(n), params_stack))
     return carry
 

@@ -1,7 +1,7 @@
 """Training loop + the CACS-hosted TrainerApp.
 
 ``make_train_step`` builds the jitted (and, under a mesh, fully sharded)
-train step used by both the real trainer and the multi-pod dry-run.
+train step that ``TrainerApp``, the launchers and the chip benchmark run.
 
 ``TrainerApp`` adapts a JAX training job to the CACS Application protocol —
 the 2026 analogue of the paper's long-running MPI application: it is

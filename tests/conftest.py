@@ -1,8 +1,8 @@
 """Shared test helpers.
 
 NOTE: no XLA_FLAGS manipulation here — smoke tests must see the real single
-CPU device. Multi-device tests (resharding, dry-run) spawn subprocesses
-that set --xla_force_host_platform_device_count themselves.
+CPU device. Multi-device tests (resharding, the sharded train step) spawn
+subprocesses that set --xla_force_host_platform_device_count themselves.
 """
 import subprocess
 import sys

@@ -126,7 +126,7 @@ def test_decode_updates_the_donated_cache_in_place(one_chip,
 
 def test_sharded_decode_updates_the_donated_cache_in_place(
         no_persistent_cache):
-    """The dry-run's decode cell on a 16-chip tensor-parallel slice: 8 KV
+    """A decode step on a 16-chip tensor-parallel slice: 8 KV
     heads do not divide 16, so each chip holds an 8-wide slice of
     head_dim. The step still writes its shard of the cache in place."""
     from jax.experimental import topologies

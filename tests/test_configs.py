@@ -1,8 +1,7 @@
 """Config registry + analytic parameter-count sanity."""
 import pytest
 
-from repro.configs import (ARCH_REGISTRY, ASSIGNED_ARCHS, SHAPES, get_config,
-                           reduced, shape_applicable)
+from repro.configs import ASSIGNED_ARCHS, get_config, reduced
 
 EXPECTED = {
     # arch -> (published total params, tolerance fraction)
@@ -43,21 +42,6 @@ def test_moe_active_params():
     assert mav.active_param_count() < 0.1 * mav.param_count()
     scout = get_config("llama4-scout-17b-a16e")
     assert scout.active_param_count() < 0.35 * scout.param_count()
-
-
-def test_shape_grid():
-    assert set(SHAPES) == {"train_4k", "prefill_32k", "decode_32k",
-                           "long_500k"}
-    # long_500k only for sub-quadratic archs
-    for arch in ASSIGNED_ARCHS:
-        cfg = get_config(arch)
-        ok, why = shape_applicable(cfg, SHAPES["long_500k"])
-        assert ok == cfg.subquadratic, (arch, why)
-        for s in ("train_4k", "prefill_32k", "decode_32k"):
-            assert shape_applicable(cfg, SHAPES[s])[0]
-    live = sum(1 for a in ASSIGNED_ARCHS for s in SHAPES.values()
-               if shape_applicable(get_config(a), s)[0])
-    assert live == 37   # 33 universal + 4 subquadratic long_500k
 
 
 @pytest.mark.parametrize("arch", sorted(ASSIGNED_ARCHS))

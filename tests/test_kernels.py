@@ -102,13 +102,21 @@ def test_qsnap_roundtrip(n, dtype):
     assert err.max() <= bound + (0.04 if dtype == jnp.bfloat16 else 0)
 
 
-def test_qsnap_matches_host_codec_bitexact():
-    x = jax.random.normal(KEY, (4096,), jnp.float32) * 3
-    codes_d, scales_d, _ = ops.qsnap_compress(x, impl="pallas",
-                                              interpret=True)
-    codes_h, scales_h = compression.quantize_int8(np.asarray(x))
-    np.testing.assert_array_equal(np.asarray(codes_d), codes_h)
-    np.testing.assert_allclose(np.asarray(scales_d), scales_h, rtol=1e-7)
+@pytest.mark.parametrize("n", [4096, 1000, 65536])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_qsnap_matches_host_codec_bitexact(dtype, n):
+    """The three QS01 int8 encoders agree byte for byte: the Pallas kernel
+    and the jnp oracle against the host codec of the f32-cast input, for
+    the training dtypes and for a length that goes through the padding."""
+    x = (jax.random.normal(KEY, (n,), jnp.float32) * 3).astype(dtype)
+    codes_h, scales_h = compression.quantize_int8(
+        np.asarray(x.astype(jnp.float32)))
+    for impl in ("pallas", "ref"):
+        codes, scales, n_orig = ops.qsnap_compress(x, impl=impl,
+                                                   interpret=True)
+        assert n_orig == n
+        assert np.asarray(codes).tobytes() == codes_h.tobytes(), impl
+        assert np.asarray(scales).tobytes() == scales_h.tobytes(), impl
 
 
 @pytest.mark.parametrize("rows", [257, 300])

@@ -104,3 +104,43 @@ def test_trainer_state_elastic_restore():
     print("ref", ref_loss, "elastic", got)
     assert abs(got - ref_loss) < 2e-5, (got, ref_loss)
     """, devices=8, timeout=560)
+
+
+def test_small_mesh_sharded_train_step_runs():
+    """Execute a sharded train step on a 2x4 mesh (the layout a resumed
+    job resharded to data x model runs under) and check loss finiteness +
+    param sharding layout."""
+    run_subprocess("""
+    import dataclasses
+    import jax, jax.numpy as jnp, numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.configs import get_config, reduced
+    from repro.data.pipeline import TokenPipeline
+    from repro.launch.mesh import make_test_mesh
+    from repro.models import build_model
+    from repro.sharding.specs import make_axes, param_specs
+    from repro.train import AdamWConfig, init_state, make_train_step
+    from repro.train.trainer import state_dims
+
+    cfg = dataclasses.replace(reduced(get_config("llama4-scout-17b-a16e")),
+                              dtype="float32")
+    model = build_model(cfg)
+    mesh = make_test_mesh((2, 4), ("data", "model"))
+    axes = make_axes(mesh, use_fsdp=True)
+    step = jax.jit(make_train_step(model, AdamWConfig(), axes=axes))
+    sds = jax.eval_shape(lambda: init_state(model, jax.random.PRNGKey(0)))
+    specs = param_specs(state_dims(model), sds, axes)
+    sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                      is_leaf=lambda x: isinstance(x, P))
+    state = jax.device_put(init_state(model, jax.random.PRNGKey(0)), sh)
+    pipe = TokenPipeline(cfg, 4, 32, seed=0)
+    with mesh:
+        for _ in range(2):
+            b = {k: jnp.asarray(v) for k, v in pipe.next().items()}
+            state, m = step(state, b)
+    assert np.isfinite(float(m["loss"]))
+    # MoE expert weights must actually be expert-sharded over 'model'
+    we = state["params"]["stack"]["l0_moe"]["we_u"]
+    assert "model" in str(we.sharding.spec), we.sharding
+    print("loss", float(m["loss"]))
+    """, devices=8, timeout=560)
